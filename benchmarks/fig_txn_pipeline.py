@@ -33,9 +33,10 @@ from repro.core import (ChainConfig, ChainSim, ClusterConfig, Coordinator,
                         make_txn_workload, reference_execute, serial_order)
 
 
-def _check_serial(cluster, sim, state, txns, results):
+def check_serial(cluster, sim, state, txns, results) -> dict:
     """Locks/waves drained + serial-reference store equality (the same
-    oracle fig_txn and the property tests run)."""
+    oracle fig_txn and the property tests run); returns the reference's
+    ``{global_key: value}``."""
     assert locks_all_free(state.locks), "a transaction leaked a lock"
     assert int(state.stores.pending.sum()) == 0
     assert Coordinator.waves_drained(state)
@@ -50,6 +51,7 @@ def _check_serial(cluster, sim, state, txns, results):
             f"non-atomic outcome at key {gk}: store={view[gk]} "
             f"reference={expected.get(gk, 0)}"
         )
+    return expected
 
 
 def _run_host(sim, cluster, txns, txns_per_wave=6):
@@ -63,7 +65,7 @@ def _run_host(sim, cluster, txns, txns_per_wave=6):
         results += res
         rounds += 2  # phase-1 + phase-2 host barriers per wave
     state = sim.drain(state, 4 * sim.n)
-    _check_serial(cluster, sim, state, txns, results)
+    check_serial(cluster, sim, state, txns, results)
     return results, int(state.t), rounds, state
 
 
@@ -74,7 +76,7 @@ def _run_wave(sim, cluster, txns):
     drv = TxnWaveDriver(sim, TxnPlanner(cluster))
     state, results = drv.run(state, txns)
     state = sim.drain(state, 4 * sim.n)
-    _check_serial(cluster, sim, state, txns, results)
+    check_serial(cluster, sim, state, txns, results)
     return results, drv.last_ticks, drv.last_rounds, state
 
 

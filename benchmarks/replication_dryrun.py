@@ -24,10 +24,9 @@ import json
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_config
-from repro.distributed.shard import shard_map
 from repro.roofline.analysis import ICI_BW, parse_collective_bytes
 from repro.serve import kv_cache as KV
 
@@ -57,10 +56,11 @@ def build(protocol: str, cfg, *, batch=32, page_tokens=1):
     # CR read window: the most recent 128-token page span per sequence
     window = jax.ShapeDtypeStruct(
         (CHAIN, L, batch, 128, KVh, D), jnp.bfloat16)
-    mesh = jax.make_mesh((CHAIN, 4, 16), ("chain", "data", "model"))
+    mesh = jax.make_mesh((CHAIN, 4, 16), ("chain", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     spec = P("chain")
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             step, mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=(spec, spec, spec),

@@ -18,6 +18,7 @@ from benchmarks import (fig3_read_qps, fig4_latency, fig5_mixed,
                         fig_txn_pipeline)
 from benchmarks.common import (BenchRow, measure_engine_us_per_query,
                                write_bench_json)
+from repro.compile_cache import enable_compile_cache
 
 
 def engine_rows() -> list[BenchRow]:
@@ -56,6 +57,7 @@ BENCHMARKS = [
 
 
 def main() -> None:
+    enable_compile_cache()
     all_rows: list[BenchRow] = []
     for name, runner in BENCHMARKS:
         rows = runner()

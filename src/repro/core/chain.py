@@ -328,7 +328,6 @@ from repro.core.types import (
     as_cluster,
     is_txn_op,
 )
-from repro.distributed.shard import shard_map
 
 NODE_STEPS: dict[str, Callable] = {
     "netcraq": craq.node_step,
@@ -1696,17 +1695,17 @@ class ChainDist:
             fn = step
         else:
             fn = lambda s, i, r, p, l: step(s, i, r, p, l, None)
-        # check_rep can't statically infer the lock shard's replication
+        # check_vma can't statically infer the lock shard's replication
         # through the sort/searchsorted ops inside the lock stage; the
         # replication is real by construction (the transition depends only
         # on the all-gathered batch, the gathered role row and the
         # replicated shard), asserted by test_chain_dist_lock_stage.
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )
         )

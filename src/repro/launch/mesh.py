@@ -8,13 +8,14 @@ emulation flag.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serving_mesh(*, chain: int = 4, multi_pod: bool = False):
@@ -26,10 +27,10 @@ def make_serving_mesh(*, chain: int = 4, multi_pod: bool = False):
     else:
         shape = (chain, 16 // chain, 16)
         axes = ("chain", "data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n: int | None = None, axis: str = "chain"):
     """Small mesh over whatever devices exist (tests/examples)."""
     n = n or len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))

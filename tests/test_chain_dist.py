@@ -10,10 +10,11 @@ from helpers import run_with_devices
 def test_chain_dist_write_read_roundtrip():
     out = run_with_devices("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.core import ChainConfig, ChainDist, CLIENT_BASE
 from repro.core.types import Msg, OP_READ, OP_WRITE
 
-mesh = jax.make_mesh((4,), ("chain",))
+mesh = jax.make_mesh((4,), ("chain",), axis_types=(AxisType.Auto,))
 cfg = ChainConfig(n_nodes=4, num_keys=16, num_versions=4, protocol="netcraq")
 dist = ChainDist(cfg, mesh, axis="chain")
 stores = dist.init_state()
@@ -57,10 +58,11 @@ def test_chain_dist_serves_with_dead_node():
     serving - all without re-making the step function."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.core import ChainConfig, ChainDist, Coordinator, CLIENT_BASE
 from repro.core.types import Msg, OP_READ, OP_WRITE
 
-mesh = jax.make_mesh((4,), ("chain",))
+mesh = jax.make_mesh((4,), ("chain",), axis_types=(AxisType.Auto,))
 cfg = ChainConfig(n_nodes=4, num_keys=16, num_versions=4, protocol="netcraq")
 dist = ChainDist(cfg, mesh, axis="chain")
 stores = dist.init_state()
@@ -106,10 +108,12 @@ def test_chain_dist_multichain_groups():
     scoped to each chain, writes/reads never leak across groups."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.core import ChainConfig, ClusterConfig, ChainDist, CLIENT_BASE
 from repro.core.types import Msg, OP_READ, OP_WRITE
 
-mesh = jax.make_mesh((2, 4), ("cgroup", "chain"))
+mesh = jax.make_mesh((2, 4), ("cgroup", "chain"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = ChainConfig(n_nodes=4, num_keys=16, num_versions=4, protocol="netcraq")
 dist = ChainDist(ClusterConfig(chain=cfg, n_chains=2), mesh,
                  axis="chain", group_axis="cgroup")
@@ -157,11 +161,12 @@ def test_chain_dist_lock_stage():
     across devices without a collective write-back."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.core import ChainConfig, ChainDist, CLIENT_BASE
 from repro.core.types import (Msg, OP_PREPARE, OP_PREPARE_ACK,
                               OP_PREPARE_NACK, OP_COMMIT, OP_TXN_REPLY)
 
-mesh = jax.make_mesh((4,), ("chain",))
+mesh = jax.make_mesh((4,), ("chain",), axis_types=(AxisType.Auto,))
 cfg = ChainConfig(n_nodes=4, num_keys=16, num_versions=4, protocol="netcraq")
 dist = ChainDist(cfg, mesh, axis="chain")
 stores = dist.init_state()
@@ -218,11 +223,12 @@ def test_chain_dist_telemetry_hist():
     host actually saw, per op class."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.core import ChainConfig, ChainDist, CLIENT_BASE
 from repro.core.types import (Msg, OP_READ, OP_READ_REPLY, OP_WRITE,
                               OP_WRITE_REPLY, OPCLASS_READ, OPCLASS_WRITE)
 
-mesh = jax.make_mesh((4,), ("chain",))
+mesh = jax.make_mesh((4,), ("chain",), axis_types=(AxisType.Auto,))
 cfg = ChainConfig(n_nodes=4, num_keys=16, num_versions=4, protocol="netcraq")
 dist = ChainDist(cfg, mesh, axis="chain")
 stores = dist.init_state()
@@ -281,12 +287,11 @@ print("DIST_TEL_OK")
 def test_replicated_kv_cache_protocols():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, functools
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.serve import kv_cache as KV
-from repro.distributed.shard import shard_map
 
 n = 4
-mesh = jax.make_mesh((n,), ("chain",))
+mesh = jax.make_mesh((n,), ("chain",), axis_types=(AxisType.Auto,))
 
 def craq_body(kv_new, seq):
     own, replica, ack = KV.netcraq_append(kv_new, seq, axis="chain", n=n)
@@ -300,7 +305,7 @@ def cr_body(page, seq):
 kv = jnp.arange(n*8, dtype=jnp.float32).reshape(n, 8)   # distinct per node
 seqs = jnp.arange(n, dtype=jnp.int32) + 10
 
-craq = jax.jit(shard_map(craq_body, mesh=mesh,
+craq = jax.jit(jax.shard_map(craq_body, mesh=mesh,
     in_specs=(P("chain"), P("chain")), out_specs=(P("chain"), P("chain"), P("chain"))))
 own, replica, ack = craq(kv, seqs)
 # node i>0 stores node i-1's page as the replica copy
@@ -309,7 +314,7 @@ assert jnp.allclose(replica[0], kv[0])
 # tail's seq broadcast to everyone
 assert ack.tolist() == [13]*n, ack
 
-cr = jax.jit(shard_map(cr_body, mesh=mesh,
+cr = jax.jit(jax.shard_map(cr_body, mesh=mesh,
     in_specs=(P("chain"), P("chain")), out_specs=(P("chain"), P("chain"), P("chain"))))
 fetched, committed, ack2 = cr(kv, seqs)
 # CR read: every node receives the TAIL's page
