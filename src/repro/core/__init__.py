@@ -20,6 +20,8 @@ Public surface:
   telemetry  - device-side telemetry plane (latency histograms, flight-
                recorder ring, sampled packet traces); host consumer lives
                in repro.obs
+  stages     - the tick's named stages (``stage`` scopes) and the map
+               from a compiled program's instructions to them
 """
 from repro.core.types import (  # noqa: F401
     ChainConfig,

@@ -300,6 +300,7 @@ from repro.core import loadgen as loadgen_lib
 from repro.core import telemetry as telemetry_lib
 from repro.core import txn as txn_lib
 from repro.core.metrics import Metrics, ReplyLog
+from repro.core.stages import stage
 from repro.core.store import Store
 from repro.core.telemetry import Telemetry
 from repro.core.txn import LockTable, WaveState
@@ -309,7 +310,6 @@ from repro.core.types import (
     N_OPCLASS,
     OP_READ_REPLY,
     NOWHERE,
-    OP_ACK,
     OP_NOP,
     OP_PREPARE_ACK,
     OP_PREPARE_NACK,
@@ -866,269 +866,265 @@ class ChainSim:
         alive = roles.alive          # [n] bool
         chain_pos = roles.chain_pos  # [n] int32 live-chain coordinate
 
-        # Stamp entry position on client queries, merge into inboxes.
-        # The client->entry-node leg is one link traversal (counted here;
-        # `extra` carries it into the query's hop total).  Queries injected
-        # into a dead node's lane are black-holed (the client's redirect is
-        # a host-side FailoverPolicy decision, not the fabric's) - they are
-        # dropped before any packet accounting, as are in-flight messages
-        # still parked at a node that died between ticks.
-        injected = jax.vmap(craq.stamp_entry)(injected, jnp.arange(n, dtype=jnp.int32))
-        dead_in = (
-            ((injected.op != OP_NOP) & ~alive[:, None]).sum()
-            + ((inbox.op != OP_NOP) & ~alive[:, None]).sum()
-        )
-        injected = jax.vmap(Msg.mask)(
-            injected, jnp.broadcast_to(alive[:, None], injected.op.shape)
-        )
-        inbox = jax.vmap(Msg.mask)(
-            inbox, jnp.broadcast_to(alive[:, None], inbox.op.shape)
-        )
-        inj_live = injected.op != OP_NOP
-        injected = injected._replace(
-            extra=injected.extra + inj_live.astype(jnp.int32)
-        )
-        n_injected = inj_live.sum()
-        lanes = [injected, inbox]
-        n_wave_in = jnp.zeros((), jnp.int32)
-        if self.wave_depth:
-            # Coordinator sub-ops enter at the live head (the node their
-            # locks live at), entry-stamped and leg-accounted exactly like
-            # a client query - the head cannot tell a wave PREPARE from a
-            # host-planned one (src >= WAVE_BASE >= CLIENT_BASE).
-            head = roles.head_pos[0]
-            sub_live = sub_in.op != OP_NOP
-            n_wave_in = sub_live.sum()
-            sub_in = sub_in._replace(
-                entry=jnp.where(sub_live, head, sub_in.entry),
-                extra=sub_in.extra + sub_live.astype(jnp.int32),
+        with stage("ingress"):
+            # Stamp entry position on client queries, merge into inboxes.
+            # The client->entry-node leg is one link traversal (counted here;
+            # `extra` carries it into the query's hop total).  Queries injected
+            # into a dead node's lane are black-holed (the client's redirect is
+            # a host-side FailoverPolicy decision, not the fabric's) - they are
+            # dropped before any packet accounting, as are in-flight messages
+            # still parked at a node that died between ticks.
+            injected = jax.vmap(craq.stamp_entry)(
+                injected, jnp.arange(n, dtype=jnp.int32))
+            dead_in = (
+                ((injected.op != OP_NOP) & ~alive[:, None]).sum()
+                + ((inbox.op != OP_NOP) & ~alive[:, None]).sum()
             )
-            at_head = jnp.arange(n, dtype=jnp.int32)[:, None] == head
-            sub_lane: Msg = jax.tree.map(
-                lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), sub_in
+            injected = jax.vmap(Msg.mask)(
+                injected, jnp.broadcast_to(alive[:, None], injected.op.shape)
             )
-            sub_lane = jax.vmap(Msg.mask)(
-                sub_lane,
-                jnp.broadcast_to(at_head, (n, sub_in.op.shape[0])),
+            inbox = jax.vmap(Msg.mask)(
+                inbox, jnp.broadcast_to(alive[:, None], inbox.op.shape)
             )
-            lanes.append(sub_lane)
-        full_inbox = pack_lanes(lanes)
-        # Pipeline passes are counted on arrival (pre-stage): a PREPARE
-        # resolved by the lock stage is one match-action pass like any
-        # other query.
-        live_in = full_inbox.op != OP_NOP
-
-        # Stale-route admission (partition-epoch rules, module docstring):
-        # consumed here and NACK-redirected, before the lock stage can
-        # grant a lock (or the store serve a read) this chain no longer
-        # owns.  Ops on unmoved buckets pass regardless of their stamp.
-        cap_total = full_inbox.op.shape[1]
-        flat_in: Msg = jax.tree.map(
-            lambda x: x.reshape((n * cap_total,) + x.shape[2:]), full_inbox
-        )
-        node_of_in = jnp.repeat(jnp.arange(n, dtype=jnp.int32), cap_total)
-        kept, stale_out, n_stale = stale_route_admission(
-            flat_in, pmap.slot_epoch, pmap.slot_bucket, node_of_in
-        )
-        lift_in = lambda m: jax.tree.map(
-            lambda x: x.reshape((n, cap_total) + x.shape[1:]), m
-        )
-        full_inbox = lift_in(kept)
-        stale_out = lift_in(stale_out)
-
-        # Lease expiry BEFORE the lock stage (lock-lease rules, module
-        # docstring): reclaim locks held past their lease and bump their
-        # version counters, so an expired holder's straggler COMMIT in
-        # this very batch already fails release validation and NACKs.
-        locks, n_expired = txn_lib.lease_expiry_stage(locks, t)
-
-        # Transaction stage at the live head: PREPARE/ABORT are consumed
-        # (lock edits + ACK/NACK replies), validated COMMITs pass through
-        # to the node step as write-like ops.
-        new_locks, full_inbox, txn_out, txn_counts = txn_lib.head_txn_stage(
-            locks, roles, stores, full_inbox, t=t,
-            dense_rank=self.fabric == "dense",
-        )
-
-        # Process: vmapped match-action pipeline pass on every node.
-        new_stores, outbox = jax.vmap(
-            functools.partial(self.node_step, cfg,
-                              dense_rank=self.fabric == "dense")
-        )(stores, roles, full_inbox)
-        # The lock stage's and the stale stage's replies join the node
-        # outboxes on the fabric (packet-accounted like any other reply).
-        out_lanes = [outbox, txn_out, stale_out]
-        if self.wave_depth:
-            # the coordinator's final client replies exit from the head
-            # like any tail reply (one client leg, reply-logged)
-            wf_live = wave_final.op != OP_NOP
-            wave_final = wave_final._replace(
-                src=jnp.where(wf_live, head, wave_final.src)
+            inj_live = injected.op != OP_NOP
+            injected = injected._replace(
+                extra=injected.extra + inj_live.astype(jnp.int32)
             )
-            wf_lane: Msg = jax.tree.map(
-                lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
-                wave_final,
+            n_injected = inj_live.sum()
+            lanes = [injected, inbox]
+            n_wave_in = jnp.zeros((), jnp.int32)
+            if self.wave_depth:
+                # Coordinator sub-ops enter at the live head (the node their
+                # locks live at), entry-stamped and leg-accounted exactly like
+                # a client query - the head cannot tell a wave PREPARE from a
+                # host-planned one (src >= WAVE_BASE >= CLIENT_BASE).
+                head = roles.head_pos[0]
+                sub_live = sub_in.op != OP_NOP
+                n_wave_in = sub_live.sum()
+                sub_in = sub_in._replace(
+                    entry=jnp.where(sub_live, head, sub_in.entry),
+                    extra=sub_in.extra + sub_live.astype(jnp.int32),
+                )
+                at_head = jnp.arange(n, dtype=jnp.int32)[:, None] == head
+                sub_lane: Msg = jax.tree.map(
+                    lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), sub_in
+                )
+                sub_lane = jax.vmap(Msg.mask)(
+                    sub_lane,
+                    jnp.broadcast_to(at_head, (n, sub_in.op.shape[0])),
+                )
+                lanes.append(sub_lane)
+            full_inbox = pack_lanes(lanes)
+            # Pipeline passes are counted on arrival (pre-stage): a PREPARE
+            # resolved by the lock stage is one match-action pass like any
+            # other query.
+            live_in = full_inbox.op != OP_NOP
+
+            # Stale-route admission (partition-epoch rules, module docstring):
+            # consumed here and NACK-redirected, before the lock stage can
+            # grant a lock (or the store serve a read) this chain no longer
+            # owns.  Ops on unmoved buckets pass regardless of their stamp.
+            cap_total = full_inbox.op.shape[1]
+            flat_in: Msg = jax.tree.map(
+                lambda x: x.reshape((n * cap_total,) + x.shape[2:]), full_inbox
             )
-            wf_lane = jax.vmap(Msg.mask)(
-                wf_lane,
-                jnp.broadcast_to(at_head, (n, wave_final.op.shape[0])),
+            node_of_in = jnp.repeat(jnp.arange(n, dtype=jnp.int32), cap_total)
+            kept, stale_out, n_stale = stale_route_admission(
+                flat_in, pmap.slot_epoch, pmap.slot_bucket, node_of_in
             )
-            out_lanes.append(wf_lane)
-        outbox = pack_lanes(out_lanes)
-        # A dead node emits nothing (its inbox is already empty; this pins
-        # the invariant even if a node_step ever emitted unsolicited).
-        outbox = jax.vmap(Msg.mask)(
-            outbox, jnp.broadcast_to(alive[:, None], outbox.op.shape)
-        )
-
-        # ---------------- routing fabric ----------------
-        flat: Msg = jax.tree.map(
-            lambda x: x.reshape((-1,) + x.shape[2:]), outbox
-        )  # [M]
-        is_unicast, is_mcast, is_exit, dead_letters = fabric_masks(flat, alive)
-
-        # link-traversal accounting in live-chain coordinates: a message
-        # travels |chain_pos[dst] - chain_pos[src]| live hops - a failed
-        # node is spliced out of the forwarding path, not traversed.
-        pos_of = lambda i: chain_pos[jnp.clip(i, 0, n - 1)]
-        uni_hops = jnp.abs(pos_of(flat.dst) - pos_of(flat.src))
-
-        # accumulate hop counts onto messages for latency tracking (the
-        # fabric adds the per-recipient multicast hops on each copy);
-        # the exit-hop term is dtype-pinned - a weak int32 here would
-        # flip Msg.extra's abstract value across the tick boundary
-        flat = flat._replace(
-            extra=flat.extra
-            + jnp.where(is_unicast, uni_hops, 0)
-            + is_exit.astype(jnp.int32)
-        )
-
-        # ---------------- per-node inbox build (capacity-limited) --------
-        M = flat.op.shape[0]
-        if self.fabric == "dense":
-            routed, dropped, mcast_copies, mcast_hop_sum = dense_route(
-                flat, alive, chain_pos, self.c_route
+            lift_in = lambda m: jax.tree.map(
+                lambda x: x.reshape((n, cap_total) + x.shape[1:]), m
             )
-        else:
-            # every outbox message carries src == emitting node, so one
-            # source contributes at most its own outbox width to the
-            # multicast stream - c_route + M // n is an exact lane bound
-            routed, dropped, mcast_copies, mcast_hop_sum = segmented_route(
-                flat, alive, chain_pos, self.c_route,
-                mcast_lane=self.c_route + M // n,
+            full_inbox = lift_in(kept)
+            stale_out = lift_in(stale_out)
+
+            # Lease expiry BEFORE the lock stage (lock-lease rules, module
+            # docstring): reclaim locks held past their lease and bump their
+            # version counters, so an expired holder's straggler COMMIT in
+            # this very batch already fails release validation and NACKs.
+            locks, n_expired = txn_lib.lease_expiry_stage(locks, t)
+
+            # Transaction stage at the live head: PREPARE/ABORT are consumed
+            # (lock edits + ACK/NACK replies), validated COMMITs pass through
+            # to the node step as write-like ops.
+            new_locks, full_inbox, txn_out, txn_counts = txn_lib.head_txn_stage(
+                locks, roles, stores, full_inbox, t=t,
+                dense_rank=self.fabric == "dense",
             )
 
-        packets = (
-            jnp.sum(jnp.where(is_unicast, uni_hops, 0))
-            + mcast_hop_sum
-            + jnp.sum(is_exit)  # final leg to the client
-            + n_injected        # client -> entry-node leg
-            + n_wave_in         # coordinator -> head leg (wave sub-ops)
-        )
-        msg_bytes = cfg.header_bytes + cfg.payload_bytes
-        msgs = (
-            jnp.sum(is_unicast)
-            + mcast_copies
-            + jnp.sum(is_exit)
-            + n_injected
-            + n_wave_in
-        )
+        with stage("node_step"):
+            # Process: vmapped match-action pipeline pass on every node.
+            new_stores, outbox = jax.vmap(
+                functools.partial(self.node_step, cfg,
+                                  dense_rank=self.fabric == "dense")
+            )(stores, roles, full_inbox)
+        with stage("fabric"):
+            # The lock stage's and the stale stage's replies join the node
+            # outboxes on the fabric (packet-accounted like any other reply).
+            out_lanes = [outbox, txn_out, stale_out]
+            if self.wave_depth:
+                # the coordinator's final client replies exit from the head
+                # like any tail reply (one client leg, reply-logged)
+                wf_live = wave_final.op != OP_NOP
+                wave_final = wave_final._replace(
+                    src=jnp.where(wf_live, head, wave_final.src)
+                )
+                wf_lane: Msg = jax.tree.map(
+                    lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
+                    wave_final,
+                )
+                wf_lane = jax.vmap(Msg.mask)(
+                    wf_lane,
+                    jnp.broadcast_to(at_head, (n, wave_final.op.shape[0])),
+                )
+                out_lanes.append(wf_lane)
+            outbox = pack_lanes(out_lanes)
+            # A dead node emits nothing (its inbox is already empty; this pins
+            # the invariant even if a node_step ever emitted unsolicited).
+            outbox = jax.vmap(Msg.mask)(
+                outbox, jnp.broadcast_to(alive[:, None], outbox.op.shape)
+            )
 
-        # ---------------- exits -> reply log ----------------
-        # Exits addressed back at a coordinator (client >= WAVE_BASE) are
-        # 2PC control replies for the wave table: diverted to the cluster
-        # control router (ctrl_out), never reply-logged.
-        if self.wave_depth:
-            wave_bound = is_exit & (flat.client >= WAVE_BASE)
-            ctrl_out = flat.mask(wave_bound)
-            is_exit = is_exit & ~wave_bound
-        exits = flat.mask(is_exit)
-        is_nack = exits.op == OP_WRITE_NACK
-        # 2PC control exits (phase-1 ACKs, prepare NACKs, abort acks) and
-        # stale-route redirects are logged for the planner/client but
-        # excluded from the `replies` throughput counter: only completed
-        # client operations count, and a committed transaction's
-        # completion is its tail OP_TXN_REPLY (seq >= 0).
-        is_ctrl = (
-            (exits.op == OP_PREPARE_ACK)
-            | (exits.op == OP_PREPARE_NACK)
-            | (exits.op == OP_STALE_NACK)
-            | ((exits.op == OP_TXN_REPLY) & (exits.seq < 0))
-        )
-        new_replies = replies.append(exits, t + 1,
-                                     dense=self.fabric == "dense")
+            # ---------------- routing fabric ----------------
+            flat: Msg = jax.tree.map(
+                lambda x: x.reshape((-1,) + x.shape[2:]), outbox
+            )  # [M]
+            is_unicast, is_mcast, is_exit, dead_letters = fabric_masks(flat, alive)
+
+            # link-traversal accounting in live-chain coordinates: a message
+            # travels |chain_pos[dst] - chain_pos[src]| live hops - a failed
+            # node is spliced out of the forwarding path, not traversed.
+            pos_of = lambda i: chain_pos[jnp.clip(i, 0, n - 1)]
+            uni_hops = jnp.abs(pos_of(flat.dst) - pos_of(flat.src))
+
+            # accumulate hop counts onto messages for latency tracking (the
+            # fabric adds the per-recipient multicast hops on each copy);
+            # the exit-hop term is dtype-pinned - a weak int32 here would
+            # flip Msg.extra's abstract value across the tick boundary
+            flat = flat._replace(
+                extra=flat.extra
+                + jnp.where(is_unicast, uni_hops, 0)
+                + is_exit.astype(jnp.int32)
+            )
+
+            # ---------------- per-node inbox build (capacity-limited) --------
+            M = flat.op.shape[0]
+            if self.fabric == "dense":
+                routed, dropped, _, mcast_hop_sum = dense_route(
+                    flat, alive, chain_pos, self.c_route
+                )
+            else:
+                # every outbox message carries src == emitting node, so one
+                # source contributes at most its own outbox width to the
+                # multicast stream - c_route + M // n is an exact lane bound
+                routed, dropped, _, mcast_hop_sum = segmented_route(
+                    flat, alive, chain_pos, self.c_route,
+                    mcast_lane=self.c_route + M // n,
+                )
+
+            packets = (
+                jnp.sum(jnp.where(is_unicast, uni_hops, 0))
+                + mcast_hop_sum
+                + jnp.sum(is_exit)  # final leg to the client
+                + n_injected        # client -> entry-node leg
+                + n_wave_in         # coordinator -> head leg (wave sub-ops)
+            )
+            msg_bytes = cfg.header_bytes + cfg.payload_bytes
+
+        with stage("reply_log"):
+            # ---------------- exits -> reply log ----------------
+            # Exits addressed back at a coordinator (client >= WAVE_BASE) are
+            # 2PC control replies for the wave table: diverted to the cluster
+            # control router (ctrl_out), never reply-logged.
+            if self.wave_depth:
+                wave_bound = is_exit & (flat.client >= WAVE_BASE)
+                ctrl_out = flat.mask(wave_bound)
+                is_exit = is_exit & ~wave_bound
+            exits = flat.mask(is_exit)
+            is_nack = exits.op == OP_WRITE_NACK
+            # 2PC control exits (phase-1 ACKs, prepare NACKs, abort acks) and
+            # stale-route redirects are logged for the planner/client but
+            # excluded from the `replies` throughput counter: only completed
+            # client operations count, and a committed transaction's
+            # completion is its tail OP_TXN_REPLY (seq >= 0).
+            is_ctrl = (
+                (exits.op == OP_PREPARE_ACK)
+                | (exits.op == OP_PREPARE_NACK)
+                | (exits.op == OP_STALE_NACK)
+                | ((exits.op == OP_TXN_REPLY) & (exits.seq < 0))
+            )
+            new_replies = replies.append(exits, t + 1,
+                                         dense=self.fabric == "dense")
 
         if self.telemetry:
             # ---------------- telemetry plane (telemetry-leaves rules) ----
-            # The histogram sees the SAME exit batch the reply log appends
-            # (wave-control replies already diverted), at the same t_done
-            # stamp - so histogram percentiles and exact ReplyLog ones are
-            # the same multiset whenever the log doesn't overflow.  NOP
-            # padding classifies to -1 and scatters out of bounds.
-            tel = tel._replace(lat_hist=telemetry_lib.record_latency(
-                tel.lat_hist, exits.op, exits.seq, t + 1 - exits.t_inject
-            ))
-            # Hop events from the pre-admission arrival batch: every
-            # message a live node observed this tick, including arrivals
-            # the stale-route stage then NACKs.  Exit events are the reply
-            # log's job.
-            tel = telemetry_lib.record_trace(
-                tel, flat_in.op, flat_in.qid, node_of_in, t
+            with stage("telemetry"):
+                # The histogram sees the SAME exit batch the reply log appends
+                # (wave-control replies already diverted), at the same t_done
+                # stamp - so histogram percentiles and exact ReplyLog ones are
+                # the same multiset whenever the log doesn't overflow.  NOP
+                # padding classifies to -1 and scatters out of bounds.
+                tel = tel._replace(lat_hist=telemetry_lib.record_latency(
+                    tel.lat_hist, exits.op, exits.seq, t + 1 - exits.t_inject
+                ))
+                # Hop events from the pre-admission arrival batch: every
+                # message a live node observed this tick, including arrivals
+                # the stale-route stage then NACKs.  Exit events are the reply
+                # log's job.
+                tel = telemetry_lib.record_trace(
+                    tel, flat_in.op, flat_in.qid, node_of_in, t
+                )
+
+        with stage("counters"):
+            # Per-bucket conflict heat (ROADMAP item-1 telemetry): every
+            # PREPARE the lock stage denied, scattered onto the bucket that
+            # owns the contended slot.  A raw integral the CP can EWMA-decay
+            # host-side to find buckets worth splitting or rebalancing.
+            B = metrics.conflict_heat.shape[0]
+            tko = txn_out.op.reshape(-1)
+            tkk = txn_out.key.reshape(-1)
+            bi = pmap.slot_bucket[
+                jnp.clip(tkk, 0, pmap.slot_bucket.shape[0] - 1)
+            ]
+            is_cnack = (tko == OP_PREPARE_NACK) & (bi >= 0)
+            new_heat = metrics.conflict_heat.at[
+                jnp.where(is_cnack, bi, B)
+            ].add(1, mode="drop")
+
+            new_metrics = Metrics(
+                packets=metrics.packets + packets,
+                bytes=metrics.bytes + packets * msg_bytes,
+                kv_procs=metrics.kv_procs + live_in.sum(),
+                reads_in=metrics.reads_in
+                + jnp.sum(injected.op == OP_READ),
+                writes_in=metrics.writes_in
+                + jnp.sum(injected.op == OP_WRITE),
+                replies=metrics.replies
+                + (exits.live() & ~is_nack & ~is_ctrl).sum(),
+                dirty_appends=metrics.dirty_appends
+                + (new_stores.pending.sum() - stores.pending.sum()).clip(0),
+                drops=metrics.drops + dropped.sum() + dead_in + dead_letters.sum(),
+                relay_procs=metrics.relay_procs
+                + jnp.sum(live_in & (full_inbox.op == OP_READ_REPLY)),
+                write_nacks=metrics.write_nacks + is_nack.sum(),
+                txn_commits=metrics.txn_commits + txn_counts[0],
+                txn_aborts=metrics.txn_aborts + txn_counts[1],
+                lock_conflicts=metrics.lock_conflicts + txn_counts[2],
+                stale_routes=metrics.stale_routes + n_stale,
+                # bumped by the CP (complete_rebalance), never by the tick
+                migration_moves=metrics.migration_moves,
+                # bumped by the coordinator stage in ``tick`` (the wave vmap
+                # runs outside this per-chain function)
+                wave_commits=metrics.wave_commits,
+                wave_aborts=metrics.wave_aborts,
+                wave_occupancy=metrics.wave_occupancy,
+                # bumped by the open-loop generator stage in ``run_openloop``
+                # (admission happens before the injection reaches the tick)
+                offered=metrics.offered,
+                admission_drops=metrics.admission_drops,
+                lease_expiries=metrics.lease_expiries + n_expired,
+                conflict_heat=new_heat,
             )
-
-        # Per-bucket conflict heat (ROADMAP item-1 telemetry): every
-        # PREPARE the lock stage denied, scattered onto the bucket that
-        # owns the contended slot.  A raw integral the CP can EWMA-decay
-        # host-side to find buckets worth splitting or rebalancing.
-        B = metrics.conflict_heat.shape[0]
-        tko = txn_out.op.reshape(-1)
-        tkk = txn_out.key.reshape(-1)
-        bi = pmap.slot_bucket[
-            jnp.clip(tkk, 0, pmap.slot_bucket.shape[0] - 1)
-        ]
-        is_cnack = (tko == OP_PREPARE_NACK) & (bi >= 0)
-        new_heat = metrics.conflict_heat.at[
-            jnp.where(is_cnack, bi, B)
-        ].add(1, mode="drop")
-
-        new_metrics = Metrics(
-            packets=metrics.packets + packets,
-            msgs=metrics.msgs + msgs,
-            bytes=metrics.bytes + packets * msg_bytes,
-            kv_procs=metrics.kv_procs + live_in.sum(),
-            reads_in=metrics.reads_in
-            + jnp.sum(injected.op == OP_READ),
-            writes_in=metrics.writes_in
-            + jnp.sum(injected.op == OP_WRITE),
-            acks=metrics.acks + jnp.sum(flat.op == OP_ACK),
-            replies=metrics.replies
-            + (exits.live() & ~is_nack & ~is_ctrl).sum(),
-            dirty_appends=metrics.dirty_appends
-            + (new_stores.pending.sum() - stores.pending.sum()).clip(0),
-            fwd_reads=metrics.fwd_reads
-            + jnp.sum(is_unicast & (flat.op == OP_READ)),
-            drops=metrics.drops + dropped.sum() + dead_in + dead_letters.sum(),
-            relay_procs=metrics.relay_procs
-            + jnp.sum(live_in & (full_inbox.op == OP_READ_REPLY)),
-            write_nacks=metrics.write_nacks + is_nack.sum(),
-            txn_commits=metrics.txn_commits + txn_counts[0],
-            txn_aborts=metrics.txn_aborts + txn_counts[1],
-            lock_conflicts=metrics.lock_conflicts + txn_counts[2],
-            stale_routes=metrics.stale_routes + n_stale,
-            # bumped by the CP (complete_rebalance), never by the tick
-            migration_moves=metrics.migration_moves,
-            # bumped by the coordinator stage in ``tick`` (the wave vmap
-            # runs outside this per-chain function)
-            wave_commits=metrics.wave_commits,
-            wave_aborts=metrics.wave_aborts,
-            wave_occupancy=metrics.wave_occupancy,
-            # bumped by the open-loop generator stage in ``run_openloop``
-            # (admission happens before the injection reaches the tick)
-            offered=metrics.offered,
-            admission_drops=metrics.admission_drops,
-            lease_expiries=metrics.lease_expiries + n_expired,
-            conflict_heat=new_heat,
-        )
 
         out = [new_stores, routed, new_locks, new_metrics, new_replies]
         if self.wave_depth:
@@ -1180,19 +1176,20 @@ class ChainSim:
             # PREPARE/COMMIT/ABORT sub-ops and final client replies.
             # the per-chain lease length rides in so PREP slots older than
             # the lease force-abort (lock-lease rules, module docstring)
-            wave, sub_out, sub_target, final_out, wstats = jax.vmap(
-                txn_lib.wave_coordinator_step, in_axes=(0, 0, None, 0)
-            )(state.wave, jnp.arange(self.C, dtype=jnp.int32), state.t,
-              state.locks.lease_ticks)
-            # sub-ops cross chains: one cluster-level segmented route to
-            # each key's owning chain (the per-chain fabric never crosses)
-            flat_sub: Msg = jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), sub_out
-            )
-            sub_in, sub_drop = cluster_route(
-                flat_sub, sub_target.reshape(-1), self.C,
-                self.wave_sub_capacity,
-            )
+            with stage("wave"):
+                wave, sub_out, sub_target, final_out, wstats = jax.vmap(
+                    txn_lib.wave_coordinator_step, in_axes=(0, 0, None, 0)
+                )(state.wave, jnp.arange(self.C, dtype=jnp.int32), state.t,
+                  state.locks.lease_ticks)
+                # sub-ops cross chains: one cluster-level segmented route to
+                # each key's owning chain (the per-chain fabric never crosses)
+                flat_sub: Msg = jax.tree.map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), sub_out
+                )
+                sub_in, sub_drop = cluster_route(
+                    flat_sub, sub_target.reshape(-1), self.C,
+                    self.wave_sub_capacity,
+                )
             outs = jax.vmap(
                 self._chain_tick,
                 in_axes=(0, 0, 0, 0, 0, 0, 0, pmap_axes, None, 0, 0)
@@ -1205,24 +1202,25 @@ class ChainSim:
             # land in its coord_in buffer for next tick's stage - the
             # coordinator id encodes the chain (client = WAVE_BASE +
             # chain * W + slot)
-            flat_ctrl: Msg = jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), ctrl_out
-            )
-            ctrl_tgt = jnp.where(
-                flat_ctrl.op != OP_NOP,
-                (flat_ctrl.client - WAVE_BASE) // self.wave_depth,
-                -1,
-            )
-            coord_in, ctrl_drop = cluster_route(
-                flat_ctrl, ctrl_tgt, self.C, self.coord_capacity
-            )
-            wave = wave._replace(coord_in=coord_in)
-            metrics = metrics._replace(
-                drops=metrics.drops + sub_drop + ctrl_drop,
-                wave_commits=metrics.wave_commits + wstats[0],
-                wave_aborts=metrics.wave_aborts + wstats[1],
-                wave_occupancy=metrics.wave_occupancy + wstats[2],
-            )
+            with stage("wave"):
+                flat_ctrl: Msg = jax.tree.map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), ctrl_out
+                )
+                ctrl_tgt = jnp.where(
+                    flat_ctrl.op != OP_NOP,
+                    (flat_ctrl.client - WAVE_BASE) // self.wave_depth,
+                    -1,
+                )
+                coord_in, ctrl_drop = cluster_route(
+                    flat_ctrl, ctrl_tgt, self.C, self.coord_capacity
+                )
+                wave = wave._replace(coord_in=coord_in)
+                metrics = metrics._replace(
+                    drops=metrics.drops + sub_drop + ctrl_drop,
+                    wave_commits=metrics.wave_commits + wstats[0],
+                    wave_aborts=metrics.wave_aborts + wstats[1],
+                    wave_occupancy=metrics.wave_occupancy + wstats[2],
+                )
             occupancy = wstats[2]
         else:
             outs = jax.vmap(
@@ -1244,19 +1242,20 @@ class ChainSim:
             # buffer-reuse contract, not a read ban), plus end-of-tick
             # gauges from the freshly routed inbox.  Field order is
             # telemetry.RING_FIELDS.
-            live = (inbox.op != OP_NOP).sum(axis=2)              # [C, n]
-            delta = lambda f: getattr(metrics, f) - getattr(state.metrics, f)
-            row = jnp.stack([
-                jnp.broadcast_to(state.t, (self.C,)),
-                live.sum(axis=1),
-                live.max(axis=1),
-                delta("drops"),
-                delta("lock_conflicts"),
-                occupancy,
-                delta("replies"),
-                delta("stale_routes"),
-            ], axis=1)
-            tel = jax.vmap(telemetry_lib.record_ring)(tel, row)
+            with stage("telemetry"):
+                live = (inbox.op != OP_NOP).sum(axis=2)  # [C, n]
+                delta = lambda f: getattr(metrics, f) - getattr(state.metrics, f)
+                row = jnp.stack([
+                    jnp.broadcast_to(state.t, (self.C,)),
+                    live.sum(axis=1),
+                    live.max(axis=1),
+                    delta("drops"),
+                    delta("lock_conflicts"),
+                    occupancy,
+                    delta("replies"),
+                    delta("stale_routes"),
+                ], axis=1)
+                tel = jax.vmap(telemetry_lib.record_ring)(tel, row)
         return SimState(
             stores=stores,
             inbox=inbox,
@@ -1337,13 +1336,14 @@ class ChainSim:
         must rebind both."""
         def body(carry, _):
             st, g = carry
-            inj, g, offered, shed = loadgen_lib.gen_tick(
-                g, self.cluster, arrival_width, self.c_in, st.t
-            )
-            st = st._replace(metrics=st.metrics._replace(
-                offered=st.metrics.offered + offered,
-                admission_drops=st.metrics.admission_drops + shed,
-            ))
+            with stage("gen"):
+                inj, g, offered, shed = loadgen_lib.gen_tick(
+                    g, self.cluster, arrival_width, self.c_in, st.t
+                )
+                st = st._replace(metrics=st.metrics._replace(
+                    offered=st.metrics.offered + offered,
+                    admission_drops=st.metrics.admission_drops + shed,
+                ))
             st = self.tick(st, inj)
             return (st, g), None
 

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import store as store_lib
+from repro.core.stages import stage
 from repro.core.store import Store
 from repro.core.types import (
     MULTICAST,
@@ -83,9 +84,10 @@ def node_step(cfg: ChainConfig, store: Store, roles: Roles, inbox: Msg,
     is_write = (is_write & ~nacked) | is_commit
 
     # ---------------- READ path (observes pre-step state) ----------------
-    clean = store_lib.is_clean(store, inbox.key)
-    v_clean, s_clean = store_lib.read_clean(store, inbox.key)
-    v_latest, s_latest = store_lib.read_latest(store, inbox.key)
+    with stage("store"):
+        clean = store_lib.is_clean(store, inbox.key)
+        v_clean, s_clean = store_lib.read_clean(store, inbox.key)
+        v_latest, s_latest = store_lib.read_latest(store, inbox.key)
 
     answer_local = is_read & clean                      # Algorithm 1 l.7-9
     answer_tail = is_read & ~clean & is_tail            # l.10-12
@@ -110,25 +112,29 @@ def node_step(cfg: ChainConfig, store: Store, roles: Roles, inbox: Msg,
     ).mask(answers)
 
     # ---------------- ACK path ----------------
-    new_store = store_lib.commit(store, inbox.key, inbox.value, inbox.seq, is_ack)
+    with stage("store"):
+        new_store = store_lib.commit(store, inbox.key, inbox.value, inbox.seq,
+                                     is_ack)
 
     # ---------------- WRITE path ----------------
     # Entry node stamps client writes with per-key monotone sequence numbers.
     needs_seq = is_write & (inbox.seq < 0)
-    new_store, stamped = store_lib.assign_seqs(new_store, inbox.key, needs_seq,
-                                               dense_rank=dense_rank)
+    with stage("store"):
+        new_store, stamped = store_lib.assign_seqs(
+            new_store, inbox.key, needs_seq, dense_rank=dense_rank)
     wseq = jnp.where(needs_seq, stamped, inbox.seq)
 
     if_tail_commit = is_write & is_tail
     if_appended = is_write & ~is_tail
-    new_store, accepted = store_lib.append_dirty(
-        new_store, inbox.key, inbox.value, wseq, if_appended,
-        dense_rank=dense_rank,
-    )
-    # Tail: commit directly (clean_write, Algorithm 1 l.27-28).
-    new_store = store_lib.commit(
-        new_store, inbox.key, inbox.value, wseq, if_tail_commit
-    )
+    with stage("store"):
+        new_store, accepted = store_lib.append_dirty(
+            new_store, inbox.key, inbox.value, wseq, if_appended,
+            dense_rank=dense_rank,
+        )
+        # Tail: commit directly (clean_write, Algorithm 1 l.27-28).
+        new_store = store_lib.commit(
+            new_store, inbox.key, inbox.value, wseq, if_tail_commit
+        )
 
     # Forward accepted writes toward the tail (next hop in the chain).
     fwd_write = accepted

@@ -26,15 +26,12 @@ import jax.numpy as jnp
 
 class Metrics(NamedTuple):
     packets: jax.Array        # link traversals
-    msgs: jax.Array           # logical messages generated
     bytes: jax.Array          # header+payload bytes crossing links
     kv_procs: jax.Array       # match-action pipeline passes (KV processing)
     reads_in: jax.Array
     writes_in: jax.Array
-    acks: jax.Array
     replies: jax.Array
     dirty_appends: jax.Array  # dirty commits (paper Fig.5, right axis)
-    fwd_reads: jax.Array      # reads that had to be forwarded (dirty, CRAQ)
     drops: jax.Array          # inbox-capacity drops, out-of-window drops,
                               # and traffic black-holed by dead nodes
     relay_procs: jax.Array    # reply-relay passes (CR retrace; IP-forwarded,
@@ -87,7 +84,7 @@ class Metrics(NamedTuple):
         conflict heat)."""
         z = jnp.zeros((), jnp.int32)
         return Metrics(
-            *([z] * 24),
+            *([z] * 21),
             conflict_heat=jnp.zeros((num_buckets,), jnp.int32),
         )
 

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import store as store_lib
+from repro.core.stages import stage
 from repro.core.store import Store
 from repro.core.types import (
     NOWHERE,
@@ -68,7 +69,8 @@ def node_step(cfg: ChainConfig, store: Store, roles: Roles, inbox: Msg,
     is_write = (is_write & ~nacked) | is_commit
 
     # ---------------- READ: only the tail replies ----------------
-    v0, s0 = store_lib.read_clean(store, inbox.key)
+    with stage("store"):
+        v0, s0 = store_lib.read_clean(store, inbox.key)
     tail_answers = is_read & is_tail
     fwd_read = is_read & ~is_tail
     # Reply retraces the chain: next stop is one hop back toward the entry
@@ -109,13 +111,15 @@ def node_step(cfg: ChainConfig, store: Store, roles: Roles, inbox: Msg,
 
     # ---------------- WRITE: overwrite + propagate ----------------
     needs_seq = is_write & (inbox.seq < 0)
-    new_store, stamped = store_lib.assign_seqs(store, inbox.key, needs_seq,
-                                               dense_rank=dense_rank)
+    with stage("store"):
+        new_store, stamped = store_lib.assign_seqs(store, inbox.key, needs_seq,
+                                                   dense_rank=dense_rank)
     # NetChain's 16-bit SEQ: wrap-around reproduces the overflow limitation.
     wseq = jnp.where(needs_seq, stamped % (1 << SEQ_BITS), inbox.seq)
-    new_store = store_lib.overwrite_clean(
-        new_store, inbox.key, inbox.value, wseq, is_write
-    )
+    with stage("store"):
+        new_store = store_lib.overwrite_clean(
+            new_store, inbox.key, inbox.value, wseq, is_write
+        )
     fwd_write = is_write & ~is_tail
     forwards = Msg(
         op=jnp.where(fwd_write,

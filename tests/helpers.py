@@ -1,7 +1,9 @@
 """Shared test utilities."""
 from __future__ import annotations
 
+import contextlib
 import os
+import re
 import subprocess
 import sys
 
@@ -447,3 +449,53 @@ def run_txn_waves_and_check(spec, driver="host", abandon=(), lease_ticks=None):
         for node in range(sim.n):
             np.testing.assert_array_equal(vals[c, node], vals[c, -1])
     return results
+
+
+_HLO_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+_HLO_DEF = re.compile(r"^\s*(?:ROOT\s+|ENTRY\s+)?%?([\w.\-]+)(?: = | \()")
+_HLO_TOKEN = re.compile(r"(?<![\w.\-])%?([A-Za-z_][\w.\-]*)")
+
+
+def hlo_instruction_lines(hlo_text: str) -> list[str]:
+    """An optimized HLO module's lines with what a named scope may change
+    taken out: each instruction's ``metadata={...}``, the module's
+    stack-frame and file-location tables, and the names of instructions,
+    parameters and computations, which XLA derives in part from the ops'
+    locations (each is replaced by its order of first definition)."""
+    lines, skip = [], False
+    for line in hlo_text.splitlines():
+        if line in _HLO_TABLES:
+            skip = True
+            continue
+        if skip and not line.startswith(("%", "ENTRY")):
+            continue
+        skip = False
+        lines.append(re.sub(r",? metadata=\{[^}]*\}", "", line))
+    names: dict[str, str] = {}
+    for line in lines:
+        m = _HLO_DEF.match(line)
+        if m:
+            names.setdefault(m.group(1), f"v{len(names)}")
+        # parameters in a computation's signature: "(param_0.1: s32[], ..."
+        for p in re.findall(r"[(,]\s*(?:/\*index=\d+\*/)?([\w.\-]+): ", line):
+            names.setdefault(p, f"v{len(names)}")
+    return [_HLO_TOKEN.sub(lambda t: names.get(t.group(1), t.group(0)), line)
+            for line in lines]
+
+
+@contextlib.contextmanager
+def stages_off():
+    """Every ``stage(...)`` scope of the program replaced by a null
+    context, for programs traced inside the block."""
+    from repro.core import stages
+
+    real = stages.stage
+    mods = [m for name, m in list(sys.modules.items())
+            if name.startswith("repro.") and getattr(m, "stage", None) is real]
+    for m in mods:
+        m.stage = lambda name: contextlib.nullcontext()
+    try:
+        yield
+    finally:
+        for m in mods:
+            m.stage = real

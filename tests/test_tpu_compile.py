@@ -18,6 +18,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.core import (ChainConfig, ChainDist, ChainSim, ClusterConfig, Msg,
                         make_loadgen)
+from tests.helpers import hlo_instruction_lines, stages_off
 
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -55,9 +56,9 @@ def _device_bytes(compiled) -> int:
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
 
 
-def test_openloop_scan_fits_one_v5e(topo):
+def _compile_scan(topo):
     """The fused open-loop scan (generator + tick), the one-chip served
-    path, compiles for one v5e and fits its memory."""
+    path, compiled for one v5e; a fresh engine, so each call traces anew."""
     cluster = ClusterConfig(
         chain=ChainConfig(n_nodes=4, num_keys=1024, num_versions=4,
                           value_words=4),
@@ -71,10 +72,28 @@ def test_openloop_scan_fits_one_v5e(topo):
         cluster, qps=32.0, write_fraction=0.25, txn_fraction=0.05,
         backlog_capacity=64)), one)
     lanes = sim.C * sim.n * sim.c_in
-    compiled = ChainSim._openloop_scan.lower(
-        sim, state, gen, 4, lanes, 0).compile()
-    used = _device_bytes(compiled)
+    return ChainSim._openloop_scan.lower(sim, state, gen, 4, lanes, 0).compile()
+
+
+@pytest.fixture(scope="module")
+def scan_v5e(topo):
+    return _compile_scan(topo)
+
+
+def test_openloop_scan_fits_one_v5e(scan_v5e):
+    """The open-loop scan compiles for one v5e and fits its memory."""
+    used = _device_bytes(scan_v5e)
     assert 0 < used < V5E_HBM_BYTES, used
+
+
+def test_scopes_leave_the_v5e_scan_unchanged(topo, scan_v5e):
+    """The tick's stage scopes change only metadata: with them replaced by
+    a null context the v5e program has the same instructions."""
+    with stages_off():
+        plain = _compile_scan(topo).as_text()
+    text = scan_v5e.as_text()
+    assert "vmap(store)" in text and "vmap(store)" not in plain
+    assert hlo_instruction_lines(text) == hlo_instruction_lines(plain)
 
 
 def test_dist_step_collectives_on_2x2(topo):
