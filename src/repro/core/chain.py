@@ -1090,6 +1090,10 @@ class ChainSim:
             new_heat = metrics.conflict_heat.at[
                 jnp.where(is_cnack, bi, B)
             ].add(1, mode="drop")
+            store_rows = (
+                jax.vmap(craq.commit_rows)(roles, full_inbox).sum()
+                if cfg.protocol == "netcraq" else 0
+            )
 
             new_metrics = Metrics(
                 packets=metrics.packets + packets,
@@ -1103,6 +1107,7 @@ class ChainSim:
                 + (exits.live() & ~is_nack & ~is_ctrl).sum(),
                 dirty_appends=metrics.dirty_appends
                 + (new_stores.pending.sum() - stores.pending.sum()).clip(0),
+                store_rows=metrics.store_rows + store_rows,
                 drops=metrics.drops + dropped.sum() + dead_in + dead_letters.sum(),
                 relay_procs=metrics.relay_procs
                 + jnp.sum(live_in & (full_inbox.op == OP_READ_REPLY)),

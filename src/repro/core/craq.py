@@ -68,26 +68,16 @@ def node_step(cfg: ChainConfig, store: Store, roles: Roles, inbox: Msg,
     del cfg
     B = inbox.batch
     is_read = inbox.op == OP_READ
-    is_write = inbox.op == OP_WRITE
     is_ack = inbox.op == OP_ACK
-    # Txn phase-2 write admitted by the head's lock stage: rides the chain
-    # exactly like a plain write but keeps its opcode so the tail can
-    # acknowledge with OP_TXN_REPLY.  Never frozen-NACKed - admission
-    # happened at PREPARE time (the freeze stops new PREPAREs instead).
     is_commit = inbox.op == OP_COMMIT
     is_tail = roles.is_tail
-
-    # Write freeze (recovery phase 2 copy window): client writes entering
-    # the chain are NACKed; in-flight writes (already sequenced) drain
-    # normally so the pre-freeze prefix commits before the CP copies.
-    nacked = is_write & (inbox.seq < 0) & roles.frozen
-    is_write = (is_write & ~nacked) | is_commit
+    is_write, nacked = _writes(roles, inbox)
 
     # ---------------- READ path (observes pre-step state) ----------------
     with stage("store"):
         clean = store_lib.is_clean(store, inbox.key)
-        v_clean, s_clean = store_lib.read_clean(store, inbox.key)
-        v_latest, s_latest = store_lib.read_latest(store, inbox.key)
+        (v_clean, s_clean), (v_latest, s_latest) = store_lib.read_versions(
+            store, inbox.key)
 
     answer_local = is_read & clean                      # Algorithm 1 l.7-9
     answer_tail = is_read & ~clean & is_tail            # l.10-12
@@ -200,6 +190,31 @@ def node_step(cfg: ChainConfig, store: Store, roles: Roles, inbox: Msg,
 
     outbox = Msg.concat([replies, forwards, acks, wreplies])
     return new_store, outbox
+
+
+def _writes(roles: Roles, inbox: Msg):
+    """(writes the node step applies, client writes the freeze NACKs)."""
+    is_write = inbox.op == OP_WRITE
+    # Write freeze (recovery phase 2 copy window): client writes entering
+    # the chain are NACKed; in-flight writes (already sequenced) drain
+    # normally so the pre-freeze prefix commits before the CP copies.
+    nacked = is_write & (inbox.seq < 0) & roles.frozen
+    # Txn phase-2 write admitted by the head's lock stage: rides the chain
+    # exactly like a plain write but keeps its opcode so the tail can
+    # acknowledge with OP_TXN_REPLY.  Never frozen-NACKed - admission
+    # happened at PREPARE time (the freeze stops new PREPAREs instead).
+    return (is_write & ~nacked) | (inbox.op == OP_COMMIT), nacked
+
+
+def commit_rows(roles: Roles, inbox: Msg) -> jax.Array:
+    """The store rows ``node_step``'s two commits rewrite for ``inbox``: one
+    per distinct key its ACKs name with a seq, and one per distinct key the
+    tail commits (a tail write always carries a seq)."""
+    is_write, _ = _writes(roles, inbox)
+    rows = lambda live: store_lib.first_of_key(inbox.key, live).sum(
+        dtype=jnp.int32)
+    return (rows((inbox.op == OP_ACK) & (inbox.seq >= 0))
+            + rows(is_write & roles.is_tail))
 
 
 def stamp_entry(inbox: Msg, my_pos) -> Msg:

@@ -32,6 +32,9 @@ class Metrics(NamedTuple):
     writes_in: jax.Array
     replies: jax.Array
     dirty_appends: jax.Array  # dirty commits (paper Fig.5, right axis)
+    store_rows: jax.Array     # store rows the node steps' commits rewrote
+                              # (NetCRAQ ACKs and tail commits: one per
+                              # distinct key a node commits in a tick)
     drops: jax.Array          # inbox-capacity drops, out-of-window drops,
                               # and traffic black-holed by dead nodes
     relay_procs: jax.Array    # reply-relay passes (CR retrace; IP-forwarded,
@@ -84,7 +87,7 @@ class Metrics(NamedTuple):
         conflict heat)."""
         z = jnp.zeros((), jnp.int32)
         return Metrics(
-            *([z] * 21),
+            *([z] * 22),
             conflict_heat=jnp.zeros((num_buckets,), jnp.int32),
         )
 

@@ -41,10 +41,6 @@ class Store(NamedTuple):
     def num_keys(self) -> int:
         return self.values.shape[0]
 
-    @property
-    def num_versions(self) -> int:
-        return self.values.shape[1]
-
 
 def init_store(cfg: ChainConfig) -> Store:
     K, V, W = cfg.num_keys, cfg.num_versions, cfg.value_words
@@ -95,14 +91,20 @@ def batch_rank(keys: jax.Array, active: jax.Array,
     return jnp.zeros((b,), jnp.int32).at[order].set(rank_sorted)
 
 
-def per_key_count(keys: jax.Array, active: jax.Array, num_keys: int) -> jax.Array:
-    """count[k] = number of active batch entries with key k."""
-    return jnp.zeros((num_keys,), jnp.int32).at[keys].add(active.astype(jnp.int32))
-
-
 # ---------------------------------------------------------------------------
 # Reads
 # ---------------------------------------------------------------------------
+# Rows are read and written whole, through the [K, V*W] view of ``values``.
+# The TPU gathers and scatters the rows of a 2-D array with the key axis
+# minor, so every access to the store asks for one layout, and the tick
+# copies no store into another layout between its reads and its writes.
+def _get_rows(store: Store, keys: jax.Array):
+    """The rows ``keys`` name: values [B, V, W] and seqs [B, V]."""
+    K, V, W = store.values.shape
+    vals = store.values.reshape(K, V * W)[keys].reshape(-1, V, W)
+    return vals, store.seqs[keys]
+
+
 def read_clean(store: Store, keys: jax.Array):
     """Value + seq of the committed version (cell 0). [B] -> ([B,W],[B])."""
     return store.values[keys, 0], store.seqs[keys, 0]
@@ -118,6 +120,22 @@ def read_latest(store: Store, keys: jax.Array):
     )
 
 
+def _cell(x: jax.Array, pick: jax.Array) -> jax.Array:
+    """The cell of each row of ``x`` [B, V, ...] that the one-hot ``pick``
+    [B, V] marks: a masked sum over the V cells, not a gather."""
+    pick = pick.reshape(pick.shape + (1,) * (x.ndim - 2))
+    return jnp.where(pick, x, 0).sum(axis=1, dtype=x.dtype)
+
+
+def read_versions(store: Store, keys: jax.Array):
+    """``read_clean`` and ``read_latest`` of ``keys`` from one gather of
+    their rows: ((value [B,W], seq [B]) of cell 0, the same of the newest
+    cell)."""
+    vals, seqs = _get_rows(store, keys)
+    latest = jnp.arange(vals.shape[1]) == store.pending[keys][:, None]
+    return (vals[:, 0], seqs[:, 0]), (_cell(vals, latest), _cell(seqs, latest))
+
+
 def is_clean(store: Store, keys: jax.Array) -> jax.Array:
     return store.pending[keys] == 0
 
@@ -125,6 +143,46 @@ def is_clean(store: Store, keys: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Writes
 # ---------------------------------------------------------------------------
+def _put_rows(store: Store, keys, vals, seqs, pending, rep) -> Store:
+    """Write whole rows back: entry ``i`` with ``rep[i]`` replaces row
+    ``keys[i]`` with ``vals[i]``, ``seqs[i]`` and ``pending[i]``.  At most one
+    entry per key may be marked (``first_of_key``); the others scatter out
+    of bounds and are dropped, so duplicate keys cannot race (XLA scatter
+    order with duplicate indices is undefined).  Rows no entry marks are
+    neither read nor written."""
+    K, V, W = store.values.shape
+    row = jnp.where(rep, keys, K)  # out-of-bounds sentinel row
+    put = lambda a, x: a.at[row].set(x, mode="drop")
+    return store._replace(
+        values=put(store.values.reshape(K, V * W),
+                   vals.reshape(-1, V * W)).reshape(K, V, W),
+        seqs=put(store.seqs, seqs),
+        pending=put(store.pending, pending),
+    )
+
+
+def _same_key(keys: jax.Array, live: jax.Array) -> jax.Array:
+    """[B, B]: entry ``j`` is live and names the key of entry ``i``.  A
+    batch is a node's inbox, so comparing it with itself costs less than a
+    scatter into a [K] vector and the gather back."""
+    return (keys[:, None] == keys[None, :]) & live[None, :]
+
+
+def first_index(keys: jax.Array, live: jax.Array) -> jax.Array:
+    """For each entry, the batch index of the first live entry with its key
+    (the batch size where there is none)."""
+    b = keys.shape[0]
+    idx = jnp.arange(b, dtype=jnp.int32)
+    return jnp.where(_same_key(keys, live), idx[None, :], b).min(axis=1)
+
+
+def first_of_key(keys: jax.Array, live: jax.Array) -> jax.Array:
+    """True at the first live entry of each key: one entry per distinct
+    live key."""
+    idx = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    return live & (first_index(keys, live) == idx)
+
+
 def assign_seqs(store: Store, keys: jax.Array, needs: jax.Array,
                 dense_rank: bool = False):
     """Stamp unsequenced client writes with per-key monotone seqs.
@@ -134,7 +192,8 @@ def assign_seqs(store: Store, keys: jax.Array, needs: jax.Array,
     """
     rank = batch_rank(keys, needs, dense=dense_rank)
     seqs = store.next_seq[keys] + rank
-    counts = per_key_count(keys, needs, store.num_keys)
+    counts = jnp.zeros((store.num_keys,), jnp.int32).at[keys].add(
+        needs.astype(jnp.int32))
     new_next = store.next_seq + counts
     return store._replace(next_seq=new_next), jnp.where(needs, seqs, -1)
 
@@ -144,24 +203,26 @@ def append_dirty(store: Store, keys, values, seqs, active,
     """Append dirty versions at cells ``pending+1+rank``; drop if the window
     is exceeded (Algorithm 1 line 22-23).
 
-    Returns (new_store, accepted[B] bool).
+    The accepted appends of a key land in the row of its first accepted
+    entry, which is written back whole.  Returns (new_store, accepted[B]).
     """
-    V = store.num_versions
+    V = store.values.shape[1]
+    b = keys.shape[0]
     rank = batch_rank(keys, active, dense=dense_rank)
-    slot = store.pending[keys] + 1 + rank
+    pending = store.pending[keys]
+    slot = pending + 1 + rank
     accepted = active & (slot <= V - 1)
-    # Scatter accepted writes; (key, slot) pairs are unique among accepted
-    # entries by construction, and rejected entries scatter out of bounds
-    # (mode='drop') so they can't race accepted ones.
-    safe_slot = jnp.where(accepted, slot, V)
-    safe_key = jnp.where(accepted, keys, store.num_keys)
-    new_values = store.values.at[safe_key, safe_slot].set(values, mode="drop")
-    new_seqs = store.seqs.at[safe_key, safe_slot].set(seqs, mode="drop")
-    counts = jnp.zeros((store.num_keys,), jnp.int32).at[keys].add(
-        jnp.where(accepted, 1, 0)
-    )
+    first = first_index(keys, accepted)
+    rep = accepted & (first == jnp.arange(b, dtype=jnp.int32))
+    # (first, slot) pairs are unique among accepted entries by
+    # construction; rejected entries scatter out of bounds.
+    at = (jnp.where(accepted, first, b), slot)
+    row_vals, row_seqs = _get_rows(store, keys)
+    row_vals = row_vals.at[at].set(values, mode="drop")
+    row_seqs = row_seqs.at[at].set(seqs, mode="drop")
+    n_new = jnp.zeros((b,), jnp.int32).at[at[0]].add(1, mode="drop")
     return (
-        store._replace(values=new_values, seqs=new_seqs, pending=store.pending + counts),
+        _put_rows(store, keys, row_vals, row_seqs, pending + n_new, rep),
         accepted,
     )
 
@@ -171,52 +232,46 @@ def commit(store: Store, keys, values, seqs, active):
     of ``key`` (cell 0) for the *largest* seq per key in the batch, then
     compact: delete all dirty versions with seq <= committed seq and shift
     the remainder down (versions are stored in increasing seq order).
+
+    Only the rows the batch names are read and written, O(B*V*W) beside
+    the [B, B] comparison of the batch's keys: each touched key's row is
+    rebuilt from the first entry that carries its largest seq.  A key
+    whose active entries all carry a negative seq is left as it is.
     """
-    K, V, W = store.values.shape
+    V = store.values.shape[1]
     active = active.astype(bool)
 
     # Per-key max committed seq in this batch (acks are cumulative).
-    neg = jnp.full((K,), -1, jnp.int32)
-    ack_seq = neg.at[keys].max(jnp.where(active, seqs, -1))
+    ack_seq = jnp.where(_same_key(keys, active), seqs[None, :], -1).max(axis=1)
+    rep = first_of_key(keys, active & (seqs == ack_seq) & (ack_seq >= 0))
 
-    # Which batch entry supplies the value for each key: the one whose seq
-    # equals the per-key max.  Non-winners scatter out of bounds and are
-    # dropped - scattering a where()-writeback instead would race the
-    # winner (XLA scatter order with duplicate indices is undefined).
-    is_winner = active & (seqs == ack_seq[keys]) & (seqs > store.seqs[keys, 0])
-    K_oob = store.num_keys  # out-of-bounds sentinel row
-    safe_key = jnp.where(is_winner, keys, K_oob)
-    cell0 = store.values[:, 0, :]
-    new_cell0 = cell0.at[safe_key].set(values, mode="drop")
-    seq0 = store.seqs[:, 0]
-    new_seq0 = seq0.at[safe_key].set(seqs, mode="drop")
+    row_vals, row_seqs = _get_rows(store, keys)
+    seq0 = row_seqs[:, 0]
+    # The representative supplies cell 0 if it is newer than the committed
+    # version; the monotone guard never rolls the committed seq backwards.
+    newer = seqs > seq0
+    cell0 = jnp.where(newer[:, None], values, row_vals[:, 0])
+    effective = jnp.maximum(seqs, seq0)  # per-key commit floor after batch
 
-    # Monotone guard: never roll the committed seq backwards.
-    effective = jnp.maximum(ack_seq, seq0)  # per-key commit floor after batch
-    touched = ack_seq >= 0
-
-    # Compact dirty region per key: keep dirty cells with seq > effective.
+    # Compact the dirty region: keep dirty cells with seq > effective.
     cell_idx = jnp.arange(V)[None, :]
-    dirty = (cell_idx >= 1) & (cell_idx <= store.pending[:, None])
-    keep = dirty & (store.seqs > effective[:, None]) & touched[:, None]
-    keep = jnp.where(touched[:, None], keep, dirty)  # untouched keys unchanged
-    # Stable argsort: kept dirty cells first, in original (seq) order.
-    order = jnp.argsort(~keep, axis=1, stable=True)  # [K, V]
-    kept_vals = jnp.take_along_axis(store.values, order[:, :, None], axis=1)
-    kept_seqs = jnp.take_along_axis(store.seqs, order[:, :, None].squeeze(-1), axis=1)
+    dirty = (cell_idx >= 1) & (cell_idx <= store.pending[keys][:, None])
+    keep = dirty & (row_seqs > effective[:, None])
     n_keep = keep.sum(axis=1).astype(jnp.int32)
+    # The cells in the order of a stable sort on ~keep: kept dirty cells
+    # first, in original (seq) order, then the others in theirs.
+    to = jnp.where(keep, jnp.cumsum(keep, axis=1),
+                   n_keep[:, None] + jnp.cumsum(~keep, axis=1)) - 1
+    moved = to[:, None, :] == jnp.arange(V)[None, :, None]  # [B, to, from]
+    kept_vals = jax.vmap(_cell, (None, 1), 1)(row_vals, moved)
+    kept_seqs = jax.vmap(_cell, (None, 1), 1)(row_seqs, moved)
 
-    # Rebuild rows only for touched keys; shift kept versions to cells 1..n.
-    shifted_vals = jnp.concatenate([new_cell0[:, None, :], kept_vals[:, : V - 1]], axis=1)
-    shifted_seqs = jnp.concatenate([new_seq0[:, None], kept_seqs[:, : V - 1]], axis=1)
-    # Blank cells beyond the kept region.
-    valid = cell_idx <= n_keep[:, None]
-    shifted_seqs = jnp.where(valid, shifted_seqs, -1)
-
-    out_values = jnp.where(touched[:, None, None], shifted_vals, store.values)
-    out_seqs = jnp.where(touched[:, None], shifted_seqs, store.seqs)
-    out_pending = jnp.where(touched, n_keep, store.pending)
-    return store._replace(values=out_values, seqs=out_seqs, pending=out_pending)
+    # Shift kept versions to cells 1..n and blank the cells beyond them.
+    new_vals = jnp.concatenate([cell0[:, None, :], kept_vals[:, : V - 1]], axis=1)
+    new_seqs = jnp.concatenate(
+        [jnp.where(newer, seqs, seq0)[:, None], kept_seqs[:, : V - 1]], axis=1)
+    new_seqs = jnp.where(cell_idx <= n_keep[:, None], new_seqs, -1)
+    return _put_rows(store, keys, new_vals, new_seqs, n_keep, rep)
 
 
 def overwrite_clean(store: Store, keys, values, seqs, active):

@@ -451,6 +451,64 @@ def run_txn_waves_and_check(spec, driver="host", abandon=(), lease_ticks=None):
     return results
 
 
+
+# ---------------------------------------------------------------------------
+# Store commit reference (used by the property tests in test_store.py)
+# ---------------------------------------------------------------------------
+def dense_commit(store, keys, values, seqs, active):
+    """The store's commit as it was first written: every row of the store
+    is rebuilt, O(K*V*W), and rows no batch entry touches are selected back
+    unchanged.  The reference ``store.commit`` must match leaf by leaf
+    (tests/test_store.py)."""
+    import jax.numpy as jnp
+
+    K, V, W = store.values.shape
+    active = active.astype(bool)
+
+    # Per-key max committed seq in this batch (acks are cumulative).
+    neg = jnp.full((K,), -1, jnp.int32)
+    ack_seq = neg.at[keys].max(jnp.where(active, seqs, -1))
+
+    # Which batch entry supplies the value for each key: the one whose seq
+    # equals the per-key max.  Non-winners scatter out of bounds and are
+    # dropped - scattering a where()-writeback instead would race the
+    # winner (XLA scatter order with duplicate indices is undefined).
+    is_winner = active & (seqs == ack_seq[keys]) & (seqs > store.seqs[keys, 0])
+    K_oob = store.num_keys  # out-of-bounds sentinel row
+    safe_key = jnp.where(is_winner, keys, K_oob)
+    cell0 = store.values[:, 0, :]
+    new_cell0 = cell0.at[safe_key].set(values, mode="drop")
+    seq0 = store.seqs[:, 0]
+    new_seq0 = seq0.at[safe_key].set(seqs, mode="drop")
+
+    # Monotone guard: never roll the committed seq backwards.
+    effective = jnp.maximum(ack_seq, seq0)  # per-key commit floor after batch
+    touched = ack_seq >= 0
+
+    # Compact dirty region per key: keep dirty cells with seq > effective.
+    cell_idx = jnp.arange(V)[None, :]
+    dirty = (cell_idx >= 1) & (cell_idx <= store.pending[:, None])
+    keep = dirty & (store.seqs > effective[:, None]) & touched[:, None]
+    keep = jnp.where(touched[:, None], keep, dirty)  # untouched keys unchanged
+    # Stable argsort: kept dirty cells first, in original (seq) order.
+    order = jnp.argsort(~keep, axis=1, stable=True)  # [K, V]
+    kept_vals = jnp.take_along_axis(store.values, order[:, :, None], axis=1)
+    kept_seqs = jnp.take_along_axis(store.seqs, order[:, :, None].squeeze(-1), axis=1)
+    n_keep = keep.sum(axis=1).astype(jnp.int32)
+
+    # Rebuild rows only for touched keys; shift kept versions to cells 1..n.
+    shifted_vals = jnp.concatenate([new_cell0[:, None, :], kept_vals[:, : V - 1]], axis=1)
+    shifted_seqs = jnp.concatenate([new_seq0[:, None], kept_seqs[:, : V - 1]], axis=1)
+    # Blank cells beyond the kept region.
+    valid = cell_idx <= n_keep[:, None]
+    shifted_seqs = jnp.where(valid, shifted_seqs, -1)
+
+    out_values = jnp.where(touched[:, None, None], shifted_vals, store.values)
+    out_seqs = jnp.where(touched[:, None], shifted_seqs, store.seqs)
+    out_pending = jnp.where(touched, n_keep, store.pending)
+    return store._replace(values=out_values, seqs=out_seqs, pending=out_pending)
+
+
 _HLO_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 _HLO_DEF = re.compile(r"^\s*(?:ROOT\s+|ENTRY\s+)?%?([\w.\-]+)(?: = | \()")
 _HLO_TOKEN = re.compile(r"(?<![\w.\-])%?([A-Za-z_][\w.\-]*)")

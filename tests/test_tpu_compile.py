@@ -7,6 +7,7 @@ topology is described inside a fixture, so only the worker that runs this
 file loads the TPU library.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -21,6 +22,7 @@ from repro.core import (ChainConfig, ChainDist, ChainSim, ClusterConfig, Msg,
 from tests.helpers import hlo_instruction_lines, stages_off
 
 V5E_HBM_BYTES = 16 * 10**9
+SCAN_KEYS = 1024  # keys per chain of the scan compiled below
 
 
 @pytest.fixture(scope="module")
@@ -60,7 +62,7 @@ def _compile_scan(topo):
     """The fused open-loop scan (generator + tick), the one-chip served
     path, compiled for one v5e; a fresh engine, so each call traces anew."""
     cluster = ClusterConfig(
-        chain=ChainConfig(n_nodes=4, num_keys=1024, num_versions=4,
+        chain=ChainConfig(n_nodes=4, num_keys=SCAN_KEYS, num_versions=4,
                           value_words=4),
         n_chains=2,
     )
@@ -94,6 +96,23 @@ def test_scopes_leave_the_v5e_scan_unchanged(topo, scan_v5e):
     text = scan_v5e.as_text()
     assert "vmap(store)" in text and "vmap(store)" not in plain
     assert hlo_instruction_lines(text) == hlo_instruction_lines(plain)
+
+
+def test_store_sorts_no_row_of_the_whole_store(scan_v5e):
+    """The store's commit compacts only the rows its batch names: no sort
+    of the ``store`` stage has an operand with the store's key axis."""
+    from repro.core.stages import op_stages
+
+    text = scan_v5e.as_text()
+    smap = op_stages(text)
+    assert "store" in smap.values()
+    sorts = [line for line in text.splitlines()
+             if re.search(r" sort\(", line)
+             and smap.get(line.split("=")[0].split()[-1].lstrip("%")) == "store"]
+    for line in sorts:
+        dims = [d for shape in re.findall(r"\[([\d,]*)\]", line)
+                for d in shape.split(",")]
+        assert str(SCAN_KEYS) not in dims, line
 
 
 def test_dist_step_collectives_on_2x2(topo):
