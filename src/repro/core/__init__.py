@@ -63,7 +63,14 @@ from repro.core.telemetry import (  # noqa: F401
     latency_bucket,
 )
 from repro.core.store import Store, init_store  # noqa: F401
-from repro.core.chain import ChainDist, ChainSim, SimState, full_roles_table  # noqa: F401
+from repro.core.chain import (  # noqa: F401
+    ChainDist,
+    ChainSim,
+    DistState,
+    SimState,
+    full_roles_table,
+    ring_order,
+)
 from repro.core.coordinator import ChainMembership, Coordinator, FailoverPolicy  # noqa: F401
 from repro.core.failure import FailureDetector, HedgedReadPolicy  # noqa: F401
 from repro.core.metrics import Metrics, ReplyLog  # noqa: F401

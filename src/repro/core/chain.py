@@ -19,14 +19,15 @@ benchmarks/fig_tick_cost.py).  ``tick`` donates the state buffers and
 ``run`` drains through one fused ``lax.scan``, so a tick allocates no new
 cluster state and the drain pays one dispatch, not sixteen.
 
-``ChainDist`` - the production engine: one chain node per device along a
-named mesh axis under ``shard_map``.  Write propagation uses
-``jax.lax.ppermute`` (one ICI hop per chain hop, exactly the paper's
-next-hop forwarding), dirty-read fetch and ACK multicast use a masked
-``all_gather`` (the ICI ring acting as the multicast tree).  With a second
-``group_axis`` on the mesh, C chains run side by side - the collectives are
-scoped to the position axis, so each chain group exchanges only within
-itself.  The multi-pod dry-run lowers this engine on the production meshes.
+``ChainDist`` - the engine on a device mesh: one chain position per device
+along a named mesh axis under ``shard_map``.  Its exchange hands each
+device the messages addressed to it in one ``all_to_all`` of per-position
+blocks, in the fabric's order and with its drops, so the mesh serves what
+the simulator serves.  ``run_openloop`` runs the open-loop scan with the
+chains as a local axis of every device; ``make_step`` runs one tick per
+call, with C chains side by side on a second ``group_axis`` - the
+collectives name only the position axis, so each chain group exchanges
+only within itself.
 
 Both engines share the per-node control logic in ``craq.py``/``netchain.py``.
 
@@ -1398,35 +1399,90 @@ class ChainSim:
 # ---------------------------------------------------------------------------
 # Distributed engine (shard_map over mesh axes)
 # ---------------------------------------------------------------------------
+class DistState(NamedTuple):
+    """State of ``ChainDist.run_openloop``: ``SimState``'s layout with the
+    node axis on the mesh.  Leaves marked *per chip* are ``[C, n, ...]``
+    sharded on ``n`` (each chip holds its own column); the rest are
+    replicated on every chip."""
+
+    stores: Store        # [C, n, ...] per chip: one replica of every chain
+    inbox: Msg           # [C, n, c_route] per chip
+    locks: LockTable     # [C, K] replicated (every chip re-derives the
+                         #     head's lock transition; lock-table rules)
+    metrics: Metrics     # [C, n] per chip; summed over n they are the
+                         #     chain's counters (``dirty_appends`` is
+                         #     clipped per node, not per chain)
+    replies: ReplyLog    # [C, n, R] per chip: the replies its node emitted
+    roles: Roles         # [C, n] replicated role table (CP-owned)
+    pmap: PartitionMap   # replicated partition map (CP-owned)
+    cross_chip: jax.Array  # [C, n] per chip: live messages the chip received
+                           #     from another chip (the exchange's work)
+    t: jax.Array         # [] int32 tick counter
+
+
+def ring_order(devices) -> list:
+    """``devices`` ordered so that consecutive entries, and the last and
+    the first, are ICI neighbours: a snake over the chips' ``(x, y)``
+    coordinates (on a 2x2 host ``(0,0) (1,0) (1,1) (0,1)``).  Devices with
+    no coordinates (a CPU) keep their order."""
+    devices = list(devices)
+    if not all(hasattr(d, "coords") for d in devices):
+        return devices
+
+    def snake(d):
+        x, y = d.coords[0], d.coords[1]
+        return (y, x if y % 2 == 0 else -x)
+
+    return sorted(devices, key=snake)
+
+
+def _over_words(msg: Msg, collective) -> Msg:
+    """Apply ``collective`` to every field of ``msg`` at once: the int32
+    fields packed as the words of one array (one collective per batch, not
+    one per field)."""
+    # a field's width in words; 0 for a field of one word with no axis
+    widths = [x.shape[-1] if x.ndim > msg.op.ndim else 0 for x in msg]
+    words = collective(jnp.concatenate(
+        [x if w else x[..., None] for x, w in zip(msg, widths)], axis=-1))
+    offs = [sum(max(v, 1) for v in widths[:i]) for i in range(len(widths))]
+    return Msg(*[words[..., o:o + w] if w else words[..., o]
+                 for o, w in zip(offs, widths)])
+
+
 class ChainDist:
-    """One chain node per device along ``axis`` of ``mesh``; optionally C
-    chains side by side along ``group_axis`` (the cluster layout
-    ``(chain_group, chain_pos)``).
+    """The chain engine on a device mesh: one chain position per device
+    along ``axis``, and the chains of the cluster either side by side on a
+    second ``group_axis`` (``make_step``) or as a local axis of every
+    device (``run_openloop``).
 
-    The step function is written for use under ``shard_map``; per-node code
-    is identical to the simulator's.  Exchange primitives:
+    Both run the same per-chain tick body (``_node_tick``), written for
+    use under ``shard_map``; its per-node code is the simulator's
+    (``craq.py``/``netchain.py``, ``stale_route_admission``,
+    ``txn.head_txn_stage``).  The exchange replaces the simulator's
+    routing fabric: every device compacts, for each destination position,
+    the messages its node sends there (unicast to it, or a multicast copy
+    with its hop cost) into ``c_route`` slots in outbox order, one
+    ``all_to_all`` over ``axis`` hands each block to its destination, and
+    the destination keeps the first ``c_route`` of the blocks in source
+    order.  That is the order and the truncation of ``segmented_route``,
+    so a device's inbox, and every drop it counts, is the simulator's.
+    The collectives name only the position ``axis``: with a
+    ``group_axis`` each chain group exchanges only within itself.
 
-    * ``ppermute`` shifts write-forward traffic one hop toward the tail -
-      the chain's next-hop propagation on the ICI ring.
-    * a masked ``all_gather`` realizes both the dirty-read fetch (tail pulls
-      queries addressed to it) and the ACK multicast (everyone sees the
-      tail's ACKs) in one collective - the TPU analogue of the P4 PRE.
+    ``run_openloop`` is ``ChainSim.run_openloop`` on the mesh: a donated
+    ``lax.scan`` of the on-device generator and the tick, with the
+    ``[C, n, ...]`` state sharded on ``n`` (``DistState``).  Every device
+    runs the generator on the same replicated state and keeps its own
+    position's injection lanes, so the arrivals are the simulator's draw
+    for draw; the ``C`` chains of a device are vmapped.
 
-    Both collectives name only the position ``axis``, so when the mesh has
-    a ``group_axis`` they are automatically scoped per chain group: chains
-    exchange nothing with each other, matching the disjoint key partition.
-
-    ``ChainDist`` carries the per-chain lock shard as a fifth step argument
-    (``LockTable`` [C, K] leaves, replicated along the position axis like
-    the partition map's slot tables): transaction candidates are
-    all-gathered across the chain group and every device re-derives the
-    *identical* head lock transition (``txn.head_txn_stage`` - the lock
-    edits depend only on the gathered batch and the replicated table, so
-    the output stays replicated; each device then keeps only its own row
-    of the passed-through/reply batches).  Client txn opcodes reaching
-    this engine thus get the same admission control as the simulator's;
-    the in-network wave coordinator (wave-table rules) remains a
-    ``ChainSim`` subsystem for now.
+    The per-chain lock table is replicated along the position axis:
+    transaction candidates are all-gathered across the chain and every
+    device re-derives the *identical* head lock transition (it depends
+    only on the gathered batch, the replicated table and the role table),
+    then keeps its own row of the passed-through and reply batches.  The
+    in-network wave coordinator (wave-table rules) and the telemetry ring
+    and traces remain ``ChainSim`` subsystems for now.
     """
 
     def __init__(
@@ -1435,6 +1491,9 @@ class ChainDist:
         mesh,
         axis: str = "chain",
         group_axis: str | None = None,
+        inject_capacity: int = 64,
+        route_capacity: int = 256,
+        reply_capacity: int = 4096,
     ):
         self.cluster = as_cluster(cfg)
         self.cfg = self.cluster.chain
@@ -1443,10 +1502,9 @@ class ChainDist:
         self.group_axis = group_axis
         self.n = self.cfg.n_nodes
         self.C = self.cluster.n_chains
-        if self.C > 1:
-            assert group_axis is not None, (
-                "multi-chain ChainDist needs a group_axis on the mesh"
-            )
+        self.c_in = inject_capacity
+        self.c_route = route_capacity
+        self.reply_capacity = reply_capacity
         mesh_shape = dict(mesh.shape)
         assert mesh_shape[axis] == self.n, (
             f"mesh axis {axis!r} has {mesh_shape[axis]} devices but the "
@@ -1525,10 +1583,202 @@ class ChainDist:
             trace_len=z(0),
         )
 
+    # -- one tick of ONE chain at this device's node -----------------------
+    def _node_tick(self, store: Store, injected: Msg, inbox: Msg,
+                   roles: Roles, slot_epoch, slot_bucket, locks: LockTable,
+                   t, c_route: int):
+        """store: this node's Store; injected [c_in] and inbox [w] its
+        lanes (either may be empty); roles: the chain's [n]-leaf table;
+        slot_epoch/slot_bucket: the chain's [K] partition rows; locks: the
+        chain's [K] lock table (replicated); t: the lease clock.  Runs
+        under ``shard_map`` with this node's position as the index along
+        ``self.axis``; vmap it over a local chain axis.
+
+        Returns ``(store', inbox' [c_route], exits [M], locks', delta,
+        cross)``: the routed inbox, this node's exits to clients (masked,
+        hop-counted), this tick's ``Metrics`` increments for this node
+        (per-chain counts that every device derives alike are taken on
+        position 0) and the live messages it received from other
+        devices."""
+        n, cfg, axis = self.n, self.cfg, self.axis
+        i32 = jnp.int32
+        p = jax.lax.axis_index(axis)
+        me: Roles = jax.tree.map(lambda x: x[p], roles)
+        alive, chain_pos = roles.alive, roles.chain_pos
+        on_first = lambda x: jnp.where(p == 0, x, jnp.zeros_like(x))
+
+        with stage("ingress"):
+            # Entry stamps and the client->entry-node leg, as the
+            # simulator's ingress: only client ops carry a client source,
+            # so stamping and leg-counting the whole batch touches nothing
+            # else.  A dead node's lanes are black-holed and counted.
+            full = Msg.concat([injected, inbox])
+            full = craq.stamp_entry(full, p)
+            dead_in = jnp.where(me.alive, 0, (full.op != OP_NOP).sum())
+            full = full.mask(jnp.broadcast_to(me.alive, full.op.shape))
+            from_client = (full.op != OP_NOP) & (full.src >= CLIENT_BASE)
+            full = full._replace(extra=full.extra + from_client.astype(i32))
+            n_client = from_client.sum()
+            live_in = full.op != OP_NOP
+            full, stale_out, n_stale = stale_route_admission(
+                full, slot_epoch, slot_bucket, p
+            )
+            locks, n_expired = txn_lib.lease_expiry_stage(locks, t)
+            # Head lock stage, replicated (lock-table rules): every device
+            # sees the same [n, w] candidate batch and derives the same
+            # transition, then keeps its own row.
+            cand = is_txn_op(full.op) & (full.src >= CLIENT_BASE)
+            with stage("exchange"):
+                txn_all = _over_words(
+                    full.mask(cand),
+                    lambda x: jax.lax.all_gather(x, axis, axis=0))  # [n, w]
+            # only the head row's replies are consumed (the ACK snapshot
+            # value is read from row head_pos), so broadcasting the local
+            # store is sound on the head and immaterial elsewhere
+            bstore = jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), store
+            )
+            locks, passed_all, rep_all, txn_counts = txn_lib.head_txn_stage(
+                locks, roles, bstore, txn_all, t=t
+            )
+            passed = jax.tree.map(lambda x: x[p], passed_all)
+            txn_out = jax.tree.map(lambda x: x[p], rep_all)
+            full = jax.tree.map(
+                lambda a, b: jnp.where(
+                    cand.reshape(cand.shape + (1,) * (a.ndim - 1)), b, a
+                ),
+                full, passed,
+            )
+
+        with stage("node_step"):
+            new_store, outbox = self.node_step(cfg, store, me, full)
+
+        with stage("fabric"):
+            out = Msg.concat([outbox, txn_out, stale_out])
+            out = out.mask(jnp.broadcast_to(me.alive, out.op.shape))
+            is_unicast, is_mcast, is_exit, dead_letters = fabric_masks(out, alive)
+            pos_of = lambda i: chain_pos[jnp.clip(i, 0, n - 1)]
+            uni_hops = jnp.abs(pos_of(out.dst) - pos_of(out.src))
+            out = out._replace(
+                extra=out.extra
+                + jnp.where(is_unicast, uni_hops, 0)
+                + is_exit.astype(i32)
+            )
+            # a multicast message costs one hop per link to every live
+            # recipient (the sender's own term is zero)
+            hop_to_all = jnp.sum(
+                jnp.where(alive[None, :],
+                          jnp.abs(chain_pos[None, :] - chain_pos[:, None]), 0),
+                axis=1,
+            )
+            mcast_hops = hop_to_all[jnp.clip(out.src, 0, n - 1)]
+            packets = (
+                jnp.sum(jnp.where(is_unicast, uni_hops, 0))
+                + jnp.sum(jnp.where(is_mcast, mcast_hops, 0))
+                + jnp.sum(is_exit)
+                + n_client
+            )
+
+        with stage("exchange"):
+            # ---- per-destination blocks, in outbox order -----------------
+            M = out.op.shape[0]
+            dst_ids = jnp.arange(n, dtype=i32)[:, None]
+            deliver = (
+                (is_unicast[None, :] & (out.dst[None, :] == dst_ids))
+                | (is_mcast[None, :] & alive[:, None]
+                   & (out.src[None, :] != dst_ids))
+            )                                             # [n, M]
+            cnt = deliver.sum(axis=1, dtype=i32)          # [n]
+            csum = jnp.cumsum(deliver.astype(i32), axis=1)
+            slot = jnp.arange(c_route, dtype=i32)
+            # slot s of block d holds the (s+1)-th message for d; counting
+            # beats a binary search's rounds of gathers at these widths
+            lane = jax.vmap(lambda c: jnp.searchsorted(
+                c, slot + 1, method="compare_all"))(csum)
+            block: Msg = jax.tree.map(
+                lambda x: x[jnp.clip(lane, 0, M - 1)], out
+            )                                             # [n, c_route]
+            copy_hop = jnp.abs(chain_pos[:, None] - pos_of(block.src))
+            block = block._replace(extra=block.extra + jnp.where(
+                block.dst == MULTICAST, copy_hop, 0))
+            block = jax.vmap(Msg.mask)(block, slot[None, :] < cnt[:, None])
+            # block d to device d; row s of what arrives came from device s
+            recv = _over_words(block, lambda x: jax.lax.all_to_all(x, axis, 0, 0))
+            # ---- the first c_route deliveries, in source order -----------
+            got = (recv.op != OP_NOP).sum(axis=1, dtype=i32)   # [n] prefixes
+            ends = jnp.cumsum(got)
+            k = slot[:, None]
+            src_row = jnp.sum(ends[None, :] <= k, axis=1)      # [c_route]
+            src_row_c = jnp.clip(src_row, 0, n - 1)
+            col = slot - (ends - got)[src_row_c]
+            new_inbox: Msg = jax.tree.map(
+                lambda x: x[src_row_c, jnp.clip(col, 0, c_route - 1)], recv
+            ).mask(slot < ends[-1])
+            # what does not fit: a source's overflow beyond its block (its
+            # destination's inbox is then full) and a full inbox's rest
+            dropped = (jnp.maximum(cnt - c_route, 0).sum()
+                       + jnp.maximum(ends[-1] - c_route, 0))
+            cross = jnp.sum(jnp.where(jnp.arange(n) != p, got, 0))
+
+        with stage("counters"):
+            exits = out.mask(is_exit)
+            is_nack = exits.op == OP_WRITE_NACK
+            is_ctrl = (
+                (exits.op == OP_PREPARE_ACK)
+                | (exits.op == OP_PREPARE_NACK)
+                | (exits.op == OP_STALE_NACK)
+                | ((exits.op == OP_TXN_REPLY) & (exits.seq < 0))
+            )
+            B = self.cluster.num_buckets
+            bi = slot_bucket[jnp.clip(txn_out.key, 0, slot_bucket.shape[0] - 1)]
+            is_cnack = (txn_out.op == OP_PREPARE_NACK) & (bi >= 0)
+            heat = jnp.zeros((B,), i32).at[
+                jnp.where(is_cnack, bi, B)].add(1, mode="drop")
+            zero = jnp.zeros((), i32)
+            delta = Metrics(
+                packets=packets,
+                bytes=packets * (cfg.header_bytes + cfg.payload_bytes),
+                kv_procs=live_in.sum(),
+                reads_in=jnp.sum((injected.op == OP_READ) & me.alive),
+                writes_in=jnp.sum((injected.op == OP_WRITE) & me.alive),
+                replies=(exits.live() & ~is_nack & ~is_ctrl).sum(),
+                dirty_appends=(new_store.pending.sum()
+                               - store.pending.sum()).clip(0),
+                store_rows=(craq.commit_rows(me, full)
+                            if cfg.protocol == "netcraq" else zero),
+                drops=dropped + dead_in + dead_letters.sum(),
+                relay_procs=jnp.sum(live_in & (full.op == OP_READ_REPLY)),
+                write_nacks=is_nack.sum(),
+                txn_commits=on_first(txn_counts[0]),
+                txn_aborts=on_first(txn_counts[1]),
+                lock_conflicts=on_first(txn_counts[2]),
+                stale_routes=n_stale,
+                migration_moves=zero,
+                wave_commits=zero,
+                wave_aborts=zero,
+                wave_occupancy=zero,
+                offered=zero,
+                admission_drops=zero,
+                lease_expiries=on_first(n_expired),
+                conflict_heat=heat,
+            )
+            delta = jax.tree.map(lambda x: x.astype(i32), delta)
+        return new_store, new_inbox, exits, locks, delta, cross
+
     def make_step(self, batch_per_node: int, telemetry: bool = False):
-        cfg, axis, n = self.cfg, self.axis, self.n
+        """One tick per call, one chain per device (``C == 1``, or one
+        chain per ``group_axis`` group): the step takes
+        ``(stores, inbox, roles, pmap, locks)`` sharded on the mesh and
+        returns ``(stores', inbox', replies, locks')``.  The caller's
+        inbox (client ops and last step's routed inbox, any width) is
+        this tick's batch; ``inbox'`` and ``replies`` are
+        ``batch_per_node`` wide."""
+        assert self.group_axis is not None or self.C == 1, (
+            "make_step runs one chain per device: give a group_axis"
+        )
+        axis = self.axis
         grouped = self.group_axis is not None
-        node_step = self.node_step
+        tick = jax.vmap(functools.partial(self._node_tick, c_route=batch_per_node))
 
         def step(stores: Store, inbox: Msg, roles: Roles,
                  pmap: PartitionMap, locks: LockTable, tel=None):
@@ -1541,115 +1791,28 @@ class ChainDist:
             (``init_telemetry()``) rides the step and an updated Telemetry
             is appended to the return - same contract as the simulator's
             plane (telemetry-leaves rules, module docstring)."""
-            unshard = (lambda x: x[0, 0]) if grouped else (lambda x: x[0])
-            my_roles: Roles = jax.tree.map(unshard, roles)
-            my_pos = my_roles.my_pos
-            local_store = jax.tree.map(unshard, stores)
-            local_in = jax.tree.map(unshard, inbox)
-            # this chain's slot rows (the [C, K] tables shard per group;
-            # ungrouped engines carry the C=1 row)
-            slot_epoch = pmap.slot_epoch[0]
-            slot_bucket = pmap.slot_bucket[0]
-            # ... and its lock shard, replicated along the position axis
-            my_locks: LockTable = jax.tree.map(lambda x: x[0], locks)
-            # a dead device receives nothing and processes nothing
-            local_in = local_in.mask(
-                jnp.broadcast_to(my_roles.alive, local_in.op.shape)
-            )
-            local_in = craq.stamp_entry(local_in, my_pos)
-
-            # stale-route admission (partition-epoch rules): client ops
-            # routed under a stale map NACK back to the client instead of
-            # touching a store this chain no longer owns - the exact same
-            # helper the simulator's tick runs.
-            local_in, stale_out, _ = stale_route_admission(
-                local_in, slot_epoch, slot_bucket, my_pos
-            )
-
-            # --- head lock stage, replicated (lock-table rules) -----------
-            # Transaction candidates are all-gathered across the chain so
-            # every device sees the same [n, B] batch and re-derives the
-            # SAME lock transition (it depends only on the gathered batch,
-            # the replicated shard and the gathered role row - never on
-            # device-local store state) - the shard stays replicated with
-            # no collective write-back.  Each device then keeps its own
-            # row: passed-through COMMITs for the node step, its replies.
-            cand = is_txn_op(local_in.op) & (local_in.src >= CLIENT_BASE)
-            txn_feed = local_in.mask(cand)
-            gather = lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True)
-            txn_all: Msg = jax.tree.map(gather, txn_feed)     # [n*B]
-            txn_all = jax.tree.map(
-                lambda x: x.reshape((n, -1) + x.shape[1:]), txn_all
-            )
+            # local shards as a one-chain local axis: [1, ...]
+            local = (lambda x: x[0]) if grouped else (lambda x: x)
+            my_roles: Roles = jax.tree.map(local, roles)
             roles_all: Roles = jax.tree.map(
-                lambda x: gather(x[None]), my_roles
-            )                                                 # [n] leaves
-            # only the head row's replies are consumed (the ACK snapshot
-            # value is read from row head_pos), so broadcasting the local
-            # store is sound on the head and immaterial elsewhere
-            bstore = jax.tree.map(
-                lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
-                local_store,
+                lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True)[None],
+                my_roles,
+            )                                             # [1, n]
+            local_in = jax.tree.map(local, inbox)
+            no_inbox = jax.tree.map(lambda x: x[:, :0], local_in)
+            new_store, next_inbox, exits, new_locks, _, _ = tick(
+                jax.tree.map(local, stores), local_in, no_inbox, roles_all,
+                pmap.slot_epoch[:1], pmap.slot_bucket[:1],
+                jax.tree.map(lambda x: x[:1], locks),
+                jnp.zeros((1,), jnp.int32),
             )
-            new_locks, passed_all, rep_all, _ = txn_lib.head_txn_stage(
-                my_locks, roles_all, bstore, txn_all
-            )
-            passed_me = jax.tree.map(lambda x: x[my_pos], passed_all)
-            rep_me = jax.tree.map(lambda x: x[my_pos], rep_all)
-            local_in = jax.tree.map(
-                lambda a, b: jnp.where(
-                    cand.reshape(cand.shape + (1,) * (a.ndim - 1)), b, a
-                ),
-                local_in, passed_me,
-            )
-
-            new_store, outbox = node_step(cfg, local_store, my_roles, local_in)
-            # ... and emits nothing
-            outbox = outbox.mask(
-                jnp.broadcast_to(my_roles.alive, outbox.op.shape)
-            )
-
-            # --- next-hop traffic: ppermute one step toward the tail ------
-            # (named axis = chain position, so each chain group exchanges
-            # only within itself).  Only traffic for the *physical* ring
-            # neighbour rides the ppermute; forwarding that skips a dead
-            # device (dst == next_pos != my_pos+1) rides the fabric below.
-            to_next = outbox.mask(outbox.dst == my_pos + 1)
-            perm = [(i, i + 1) for i in range(n - 1)]
-            from_prev = jax.tree.map(
-                lambda x: jax.lax.ppermute(x, axis, perm), to_next
-            )
-
-            # --- fabric traffic: dirty-read fetch + multicast ACKs --------
-            fabric = outbox.mask(
-                (outbox.dst == MULTICAST)
-                | ((outbox.dst >= 0) & (outbox.dst != my_pos + 1))
-            )
-            all_fab: Msg = jax.tree.map(
-                lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True), fabric
-            )
-            take = (
-                (all_fab.dst == my_pos)
-                | ((all_fab.dst == MULTICAST) & (all_fab.src != my_pos))
-            ) & my_roles.alive
-            from_fabric = all_fab.mask(take)
-
-            replies = self._compact(
-                Msg.concat([
-                    outbox.mask(outbox.dst == TO_CLIENT), stale_out, rep_me,
-                ]),
-                batch_per_node,
-            )
-
-            next_inbox = self._compact(
-                Msg.concat([from_prev, from_fabric]), batch_per_node
-            )
-            reshard = (lambda x: x[None, None]) if grouped else (lambda x: x[None])
+            replies = jax.vmap(lambda m: self._compact(m, batch_per_node))(exits)
+            reshard = (lambda x: x[None]) if grouped else (lambda x: x)
             out = [
                 jax.tree.map(reshard, new_store),
                 jax.tree.map(reshard, next_inbox),
                 jax.tree.map(reshard, replies),
-                jax.tree.map(lambda x: x[None], new_locks),
+                new_locks,
             ]
             if telemetry:
                 # --- device-side latency histogram (telemetry rules) ------
@@ -1658,16 +1821,17 @@ class ChainDist:
                 # (the dist engine has no shared SimState.t), so
                 # ticks-in-flight = clock + 1 - t_inject, exactly the
                 # simulator's t_done stamp.
-                my_tel: Telemetry = jax.tree.map(unshard, tel)
+                my_tel: Telemetry = jax.tree.map(lambda x: local(x)[0], tel)
+                rep = jax.tree.map(lambda x: x[0], replies)
                 clock = my_tel.ring_cursor
                 my_tel = my_tel._replace(
                     lat_hist=telemetry_lib.record_latency(
-                        my_tel.lat_hist, replies.op, replies.seq,
-                        clock + 1 - replies.t_inject,
+                        my_tel.lat_hist, rep.op, rep.seq,
+                        clock + 1 - rep.t_inject,
                     ),
                     ring_cursor=jnp.asarray(clock + 1, jnp.int32),
                 )
-                out.append(jax.tree.map(reshard, my_tel))
+                out.append(jax.tree.map(lambda x: reshard(x[None]), my_tel))
             return tuple(out)
 
         spec = self._specs()
@@ -1685,10 +1849,7 @@ class ChainDist:
         # the lock shard replicates along the position axis, like the
         # partition map's slot tables (every device re-derives the same
         # transition from the all-gathered batch)
-        lock_spec = LockTable(
-            holder=slot_spec, client=slot_spec, version=slot_spec,
-            lease=slot_spec, lease_ticks=slot_spec,
-        )
+        lock_spec = LockTable(*([slot_spec] * len(LockTable._fields)))
         # the telemetry shard is per-device state: every leaf shards on
         # the same (group, position) axes as the stores
         tel_spec = Telemetry(*([spec] * len(Telemetry._fields)))
@@ -1714,3 +1875,136 @@ class ChainDist:
                 check_vma=False,
             )
         )
+
+    # -- the open-loop scan on the mesh -------------------------------------
+    def init_scan_state(self) -> DistState:
+        """The initial ``DistState`` of ``run_openloop`` (unplaced; put it
+        on the mesh with ``scan_shardings``)."""
+        C, n = self.C, self.n
+        per_node = lambda tree: jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (C, n) + x.shape), tree)
+        return DistState(
+            stores=per_node(store_lib.init_store(self.cfg)),
+            inbox=per_node(Msg.empty(self.c_route, self.cfg.value_words)),
+            locks=jax.vmap(lambda _: txn_lib.init_locks(self.cfg))(
+                jnp.arange(C)),
+            metrics=per_node(Metrics.zeros(self.cluster.num_buckets)),
+            replies=per_node(ReplyLog.empty(self.reply_capacity)),
+            roles=full_roles_table(n, C),
+            pmap=self.cluster.default_partition(),
+            cross_chip=jnp.zeros((C, n), jnp.int32),
+            t=jnp.zeros((), jnp.int32),
+        )
+
+    def _scan_specs(self) -> DistState:
+        node, rep = P(None, self.axis), P()
+        return DistState(
+            stores=Store(*([node] * len(Store._fields))),
+            inbox=Msg(*([node] * len(Msg._fields))),
+            locks=LockTable(*([rep] * len(LockTable._fields))),
+            metrics=Metrics(*([node] * len(Metrics._fields))),
+            replies=ReplyLog(*([node] * len(ReplyLog._fields))),
+            roles=Roles(*([rep] * len(Roles._fields))),
+            pmap=PartitionMap(*([rep] * len(PartitionMap._fields))),
+            cross_chip=node,
+            t=rep,
+        )
+
+    def scan_shardings(self) -> DistState:
+        """``NamedSharding``s of a ``DistState`` on this engine's mesh."""
+        from jax.sharding import NamedSharding
+
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s),
+                            self._scan_specs(),
+                            is_leaf=lambda x: isinstance(x, P))
+
+    def _chip_tick(self, st: DistState, injected: Msg, offered,
+                   shed) -> DistState:
+        """One tick of this device's node of every chain: ``st`` is the
+        local view ([C, ...] leaves, node axis dropped; replicated leaves
+        whole), ``injected`` its [C, c_in] lanes, ``offered``/``shed`` the
+        generator's [C] counts this device keeps."""
+        tick = functools.partial(self._node_tick, c_route=self.c_route)
+        stores, inbox, exits, locks, delta, cross = jax.vmap(
+            tick, in_axes=(0, 0, 0, 0, 0, 0, 0, None),
+        )(st.stores, injected, st.inbox, st.roles, st.pmap.slot_epoch,
+          st.pmap.slot_bucket, st.locks, st.t)
+        with stage("reply_log"):
+            replies = jax.vmap(lambda r, e: r.append(e, st.t + 1))(
+                st.replies, exits)
+        with stage("counters"):
+            delta = delta._replace(offered=offered, admission_drops=shed)
+            metrics = jax.tree.map(jnp.add, st.metrics, delta)
+        return st._replace(stores=stores, inbox=inbox, locks=locks,
+                           metrics=metrics, replies=replies,
+                           cross_chip=st.cross_chip + cross, t=st.t + 1)
+
+    @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5),
+                       donate_argnums=(1, 2))
+    def _dist_openloop_scan(self, state: DistState, gen, ticks: int,
+                            arrival_width: int, extra_ticks: int):
+        """The fused generate+tick scan on the mesh (see ``run_openloop``).
+        ``state`` AND ``gen`` are donated - callers must rebind both."""
+        specs = self._scan_specs()
+        node_axis = lambda tree, f: jax.tree.map(
+            lambda s, x: f(x) if s == P(None, self.axis) else x, specs, tree,
+            is_leaf=lambda x: isinstance(x, P))
+
+        def shard_body(state: DistState, gen):
+            p = jax.lax.axis_index(self.axis)
+            st = node_axis(state, lambda x: x[:, 0])
+            no_arrivals = jnp.zeros((self.C,), jnp.int32)
+
+            def body(carry, _):
+                st, g = carry
+                with stage("gen"):
+                    inj, g, offered, shed = loadgen_lib.gen_tick(
+                        g, self.cluster, arrival_width, self.c_in, st.t
+                    )
+                    mine = jax.tree.map(
+                        lambda x: jax.lax.dynamic_index_in_dim(
+                            x, p, axis=1, keepdims=False), inj)
+                    # per-chain counts: every device draws alike; one keeps them
+                    offered = jnp.where(p == 0, offered, 0)
+                    shed = jnp.where(p == 0, shed, 0)
+                return (self._chip_tick(st, mine, offered, shed), g), None
+
+            def drain(st, _):
+                empty = jax.tree.map(
+                    lambda x: jnp.broadcast_to(x, (self.C,) + x.shape),
+                    Msg.empty(self.c_in, self.cfg.value_words))
+                return self._chip_tick(st, empty, no_arrivals, no_arrivals), None
+
+            (st, gen), _ = jax.lax.scan(body, (st, gen), None, length=ticks)
+            if extra_ticks:
+                st, _ = jax.lax.scan(drain, st, None, length=extra_ticks)
+            return node_axis(st, lambda x: x[:, None]), gen
+
+        return jax.shard_map(
+            shard_body, mesh=self.mesh,
+            in_specs=(specs, P()), out_specs=(specs, P()),
+            check_vma=False,
+        )(state, gen)
+
+    def run_openloop(self, state: DistState, gen, ticks: int,
+                     extra_ticks: int = 0):
+        """``ChainSim.run_openloop`` on the mesh: ``ticks`` ticks of
+        on-device generation and the tick in ONE donated ``lax.scan``
+        under ``shard_map`` (then ``extra_ticks`` drain ticks with no
+        arrivals).  The arrival width is the cluster's injection lanes,
+        ``C * n * inject_capacity``, as the simulator's default.
+
+        Returns ``(state, gen)`` - BOTH inputs are donated, rebind both:
+        ``state, gen = dist.run_openloop(state, gen, ticks)``.
+        """
+        assert self.group_axis is None, (
+            "run_openloop keeps the chains on a local axis: no group_axis"
+        )
+        return self._dist_openloop_scan(
+            state, gen, ticks, self.C * self.n * self.c_in, extra_ticks
+        )
+
+    def inflight(self, state: DistState) -> int:
+        """Host-side count of ops still inside the engine (live inbox
+        slots)."""
+        return int(jnp.sum(state.inbox.op != OP_NOP))

@@ -1,7 +1,8 @@
 """The stages of the served tick, by name, and where each instruction of a
 compiled tick program belongs.
 
-Every stage of ``ChainSim.run_openloop``'s scan runs under ``stage(name)``,
+Every stage of ``ChainSim.run_openloop``'s scan, and of ``ChainDist``'s
+tick, runs under ``stage(name)``,
 a ``jax.named_scope`` whose name is one of ``STAGES``:
 
     gen        the open-loop generator and its offered/shed counters
@@ -15,6 +16,8 @@ a ``jax.named_scope`` whose name is one of ``STAGES``:
     telemetry  latency histogram, packet traces and the flight-recorder row
     counters   conflict heat and the per-tick ``Metrics``
     wave       the wave coordinator and its two cluster routes
+    exchange   ``ChainDist``'s collectives between chips and the inbox
+               compaction around them (one chain position per chip)
 
 A scope changes nothing but the ``op_name`` metadata XLA keeps on each
 instruction, so the optimized program is the same with or without it.
@@ -31,7 +34,7 @@ from typing import NamedTuple
 import jax
 
 STAGES = ("gen", "ingress", "node_step", "store", "fabric", "reply_log",
-          "telemetry", "counters", "wave")
+          "telemetry", "counters", "wave", "exchange")
 
 
 def stage(name: str):

@@ -226,13 +226,13 @@ def lease_expiry_stage(locks: LockTable, t):
 # The head's transaction stage (runs inside _chain_tick, before node_step)
 # ---------------------------------------------------------------------------
 def head_txn_stage(locks: LockTable, roles: Roles, stores, inbox: Msg,
-                   t=None, dense_rank: bool = False):
+                   t, dense_rank: bool = False):
     """Process this tick's client transaction ops at the chain's live head.
 
     ``t`` is the engine tick: every granted lock is stamped with it in
-    ``locks.lease`` so ``lease_expiry_stage`` can reclaim abandoned locks.
-    ``None`` (the ``ChainDist`` path, which carries no lease clock yet)
-    stamps 0 - inert while ``lease_ticks == LEASE_OFF``.
+    ``locks.lease`` so ``lease_expiry_stage`` can reclaim abandoned locks
+    (``ChainDist.make_step``, which has no clock, passes 0 - inert while
+    ``lease_ticks == LEASE_OFF``).
 
     ``dense_rank`` selects the O(B^2) same-key ranking of the pre-segmented
     engine (the ``fabric="dense"`` benchmark baseline; B here is the whole
@@ -253,7 +253,7 @@ def head_txn_stage(locks: LockTable, roles: Roles, stores, inbox: Msg,
     n, cap = inbox.op.shape
     K = locks.holder.shape[0]
     W = stores.values.shape[-1]
-    t_now = jnp.asarray(0 if t is None else t, jnp.int32)
+    t_now = jnp.asarray(t, jnp.int32)
     flat: Msg = jax.tree.map(
         lambda x: x.reshape((n * cap,) + x.shape[2:]), inbox
     )

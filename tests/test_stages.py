@@ -22,6 +22,8 @@ _OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (?:\(.*?\)|\S+) ([\w\-]+)\
 def test_stage_accepts_only_tick_stages():
     with stage("store"):
         pass
+    with stage("exchange"):
+        pass
     with pytest.raises(ValueError):
         stage("stores")
 
@@ -32,6 +34,8 @@ def test_stage_accepts_only_tick_stages():
     ("jit(f)/while/body/closed_call/jit(tick)/vmap(node_step)/mul", "node_step"),
     ("jit(f)/while/body/closed_call/gen/jit(_uniform)/add", "gen"),
     ("jit(f)/vmap(vmap(fabric))/jit(cumsum)/reduce_window_sum", "fabric"),
+    ("jit(f)/shard_map/while/body/closed_call/vmap(exchange)/vmap(jit("
+     "searchsorted))/vmap()/gather", "exchange"),
     ("jit(f)/while/body/closed_call/jit(tick)/vmap()/add", None),
     ("jit(store_fn)/add", None),
     ("", None),
@@ -166,7 +170,9 @@ def _scan_body(text: str) -> dict[str, str]:
 def test_scan_stage_coverage(scan):
     protocol, text = scan
     smap = stages.op_stages(text)
-    assert set(smap.values()) - {None} == set(stages.STAGES) - {"wave"}, protocol
+    # no cell of the one-chip scan runs waves; the exchange is ChainDist's
+    assert (set(smap.values()) - {None}
+            == set(stages.STAGES) - {"wave", "exchange"}), protocol
     work = [n for n, op in _scan_body(text).items() if op not in FREE]
     unscoped = [n for n in work if smap[n] is None]
     assert work and len(unscoped) <= 0.1 * len(work), (protocol, unscoped)

@@ -6,6 +6,8 @@ memory, and its HLO shows the collectives the four-chip step uses.  The
 topology is described inside a fixture, so only the worker that runs this
 file loads the TPU library.
 """
+import hashlib
+import json
 import os
 import re
 
@@ -19,7 +21,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.core import (ChainConfig, ChainDist, ChainSim, ClusterConfig, Msg,
                         make_loadgen)
-from tests.helpers import hlo_instruction_lines, stages_off
+from tests.helpers import REPO, hlo_instruction_lines, stages_off
 
 V5E_HBM_BYTES = 16 * 10**9
 SCAN_KEYS = 1024  # keys per chain of the scan compiled below
@@ -117,8 +119,9 @@ def test_store_sorts_no_row_of_the_whole_store(scan_v5e):
 
 def test_dist_step_collectives_on_2x2(topo):
     """``ChainDist.make_step`` with one chain node per chip of a 2x2 v5e:
-    next-hop forwarding compiles to a collective-permute, the fabric and
-    the lock stage to all-gathers."""
+    the exchange compiles to an all-to-all (each chip's block for each
+    position), the lock stage's candidates and the role rows to
+    all-gathers."""
     mesh = Mesh(np.asarray(topo.devices).reshape(4), ("chain",),
                 axis_types=(AxisType.Auto,))
     dist = ChainDist(ChainConfig(n_nodes=4, num_keys=1024, num_versions=4),
@@ -135,6 +138,67 @@ def test_dist_step_collectives_on_2x2(topo):
         _on(jax.eval_shape(dist.init_locks), rep),
     ).compile()
     hlo = compiled.as_text()
-    assert "collective-permute" in hlo
+    assert "all-to-all" in hlo
     assert "all-gather" in hlo
     assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+# the one-chip scan above, its optimized v5e text with metadata and names
+# taken out (``hlo_instruction_lines``): the program ``ChainDist`` must
+# leave as it is.  A change to the one-chip tick changes it on purpose.
+ONE_CHIP_SCAN = (15981, "774678f7f948fef98c201a596f5c85db"
+                        "1815703334a52dd5190151ed717e6a7f")
+
+
+def test_one_chip_scan_program_is_pinned(scan_v5e):
+    lines = hlo_instruction_lines(scan_v5e.as_text())
+    digest = hashlib.sha256("\n".join(lines).encode()).hexdigest()
+    assert (len(lines), digest) == ONE_CHIP_SCAN
+
+
+COLLECTIVE = re.compile(r" (all-gather|all-to-all|collective-permute|all-reduce"
+                        r"|reduce-scatter)(-start)?\(")
+
+
+def test_dist_scan_on_2x2_at_cell_size(topo):
+    """``ChainDist.run_openloop`` as the four-chip cell runs it (its
+    configuration file, one replica position per chip of a 2x2 v5e, laid
+    out as a ring): it fits a chip, and every collective is the
+    ``exchange`` stage's."""
+    from repro.core import ring_order
+    from repro.core.stages import op_stages
+
+    with open(os.path.join(REPO, "bench", "configs",
+                           "netcraq_dist4_16x4_1m.json")) as f:
+        cfg = json.load(f)
+    cluster = ClusterConfig(
+        chain=ChainConfig(n_nodes=cfg["replicas"],
+                          num_keys=cfg["keys"] // cfg["chains"],
+                          num_versions=cfg["num_versions"],
+                          value_words=cfg["value_words"],
+                          protocol=cfg["protocol"]),
+        n_chains=cfg["chains"],
+    )
+    ring = ring_order(topo.devices)
+    assert [tuple(d.coords[:2]) for d in ring] == [(0, 0), (1, 0), (1, 1), (0, 1)]
+    mesh = Mesh(np.asarray(ring), ("chain",), axis_types=(AxisType.Auto,))
+    seg, n, lanes = 8, cluster.n_nodes, cfg["lanes_per_node"]
+    dist = ChainDist(cluster, mesh, inject_capacity=lanes,
+                     route_capacity=cfg["route_capacity"],
+                     reply_capacity=seg * n * lanes + n * cfg["route_capacity"])
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(dist.init_scan_state), dist.scan_shardings())
+    gen = _on(jax.eval_shape(lambda: make_loadgen(
+        cluster, qps=1.0, backlog_capacity=dist.C * n * lanes)),
+        NamedSharding(mesh, P()))
+    compiled = ChainDist._dist_openloop_scan.lower(
+        dist, state, gen, seg, dist.C * n * lanes, 0).compile()
+    used = _device_bytes(compiled)
+    print(f"four-chip scan: {used} bytes per chip")
+    assert 0 < used < V5E_HBM_BYTES
+    text = compiled.as_text()
+    smap = op_stages(text)
+    found = [line.split("=")[0].split()[-1].lstrip("%")
+             for line in text.splitlines() if COLLECTIVE.search(line)]
+    assert found and all(smap[name] == "exchange" for name in found), found
